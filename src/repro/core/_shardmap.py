@@ -1,23 +1,12 @@
-"""shard_map compat: the API moved from `jax.experimental.shard_map` to
-`jax.shard_map` and renamed `check_rep` to `check_vma` along the way.  All
-SPMD entry points in this repo go through `shard_map_norep`, which disables
-the replication check under whichever name the installed jax uses."""
+"""The one shard_map wrapper: every SPMD entry point in this repo goes
+through `shard_map_norep`, which turns off the replication check
+(`check_vma`) — the per-device stats and table stacks are not provably
+replicated even where they are."""
 from __future__ import annotations
-
-import inspect
 
 import jax
 
-try:
-    _shard_map = jax.shard_map
-except AttributeError:  # jax < 0.5
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_CHECK_KW = ("check_vma"
-             if "check_vma" in inspect.signature(_shard_map).parameters
-             else "check_rep")
-
 
 def shard_map_norep(fn, mesh, in_specs, out_specs):
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{_CHECK_KW: False})
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

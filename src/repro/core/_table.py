@@ -115,8 +115,11 @@ def make_group_max(Tstar):
     value-search substitution.
     """
     msize = Tstar.size
-    perm = jnp.argsort(Tstar)
-    sorted_vals = Tstar[perm]
+    # (value, slot) keys are unique, so an unstable two-key sort returns the
+    # stable argsort — and compiles far faster for a TPU than a stable sort
+    sorted_vals, perm = lax.sort(
+        (Tstar, jnp.arange(msize, dtype=jnp.int32)), num_keys=2,
+        is_stable=False)
     run_start = jnp.concatenate(
         [jnp.ones((1,), bool), sorted_vals[1:] != sorted_vals[:-1]])
     run_id = jnp.cumsum(run_start) - 1
@@ -156,6 +159,14 @@ def hook_propagate(Tstar, cut_max, group_max, max_iter: int = 64):
     return L, iters, ~ch
 
 
+def materialize(x):
+    """Materialise block-sized index arithmetic before the gather that
+    consumes it.  Fused into the gather, the TPU compiler's code generation
+    grows with the array (tens of seconds at 256^3, minutes at 512^3);
+    behind the barrier the program compiles in seconds at any size."""
+    return lax.optimization_barrier(x)
+
+
 def value_substitute(o, chased, sorted_vals, g_sorted):
     """Final substitution for CC (Alg. 2 lines 27-33 generalised): take each
     owned label `chased` through the table, then adopt its equal-label
@@ -165,8 +176,9 @@ def value_substitute(o, chased, sorted_vals, g_sorted):
     vertices of the same local piece.  `o` is the pre-chase label; `< 0`
     (unmasked) entries stay -1.
     """
-    idx = jnp.clip(jnp.searchsorted(sorted_vals, chased),
-                   0, sorted_vals.shape[0] - 1)
+    chased = materialize(chased)
+    idx = materialize(jnp.clip(jnp.searchsorted(sorted_vals, chased),
+                               0, sorted_vals.shape[0] - 1))
     found = sorted_vals[idx] == chased
     improved = jnp.where(found & (chased >= 0),
                          jnp.maximum(g_sorted[idx], chased), chased)

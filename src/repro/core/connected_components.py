@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ._table import materialize
 from .pathcompress import path_compress, jump
 from .steepest import graph_mask_argmax, neighbor_offsets, shift_fill
 
@@ -44,6 +45,7 @@ def _grid_stitch(d: jax.Array, mask_flat: jax.Array, shape, connectivity: int,
         valid = mask_flat & (shift_fill(m_grid, off, False).ravel())
         tgt = jnp.where(valid, d, sentinel)                    # index d[v]
         val = jnp.where(valid, u_label, -1)
+        tgt, val = materialize((tgt, val))
         out = out.at[tgt].max(val, mode="drop")
     return out
 
@@ -85,14 +87,13 @@ def connected_components_grid(mask: jax.Array, connectivity: int = 6,
     field); the grid is never extracted — non-feature vertices just carry -1
     (the paper's "implicitly thresholded grids", §5).  fused_impl selects
     the pointer-init implementation (repro.kernels.ops.fused_local_phase);
-    labels are bit-identical across choices — the kernel path merely starts
-    the first compression near-converged.
+    labels are bit-identical across choices.
     """
     # lazy: repro.kernels imports repro.core.steepest at module load
     from repro.kernels.ops import fused_local_phase
     n = mask.size
     mask_flat = mask.ravel().astype(bool)
-    d0, _ = fused_local_phase(mask, connectivity, mode="cc", impl=fused_impl)
+    d0 = fused_local_phase(mask, connectivity, mode="cc", impl=fused_impl)
     stitch = lambda d: _grid_stitch(d, mask_flat, mask.shape, connectivity, n)
     res = _cc_fixpoint(d0.ravel(), stitch)
     return CCResult(res.labels.reshape(mask.shape), res.n_rounds,

@@ -57,11 +57,11 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
 from ._shardmap import shard_map_norep
 from ._table import (TableView, chase_view, check_converged, check_table_mode,
-                     make_group_max, hook_propagate, pointer_chase,
+                     make_group_max, hook_propagate, materialize,
                      sharded_fixpoint, value_substitute)
 from .stats import DPCStats
 from .steepest import neighbor_offsets, shift_fill
@@ -81,11 +81,16 @@ def make_dpc_mesh(layout, devices=None) -> Mesh:
     decomposes grid axis ``a``.
     """
     if isinstance(layout, (int, np.integer)):
-        return jax.make_mesh((int(layout),), (AXIS,), devices=devices)
-    layout = tuple(int(p) for p in layout)
-    if not 1 <= len(layout) <= len(BLOCK_AXES):
-        raise ValueError(f"layout {layout} must have 1..3 axes")
-    return jax.make_mesh(layout, BLOCK_AXES[:len(layout)], devices=devices)
+        layout, names = (int(layout),), (AXIS,)
+    else:
+        layout = tuple(int(p) for p in layout)
+        if not 1 <= len(layout) <= len(BLOCK_AXES):
+            raise ValueError(f"layout {layout} must have 1..3 axes")
+        names = BLOCK_AXES[:len(layout)]
+    # Auto axes: the labels that come back are ordinary sharded arrays that
+    # any jnp op (a gather included) accepts without an out_sharding
+    return jax.make_mesh(layout, names, (AxisType.Auto,) * len(layout),
+                         devices=devices)
 
 
 # --- static decomposition geometry ------------------------------------------
@@ -174,17 +179,6 @@ class BlockDecomp:
                     for b in range(self.layout[a]))
             self.n_valid_slots += f * (self.size // self.grid[a])
         self.pad_fraction = 1.0 - self.size / math.prod(self.padded)
-
-    def ghost_mask(self) -> np.ndarray:
-        """Boolean ext-block array marking the ghost layers."""
-        m = np.zeros(self.ext, bool)
-        for a in range(self.k):
-            idx = [slice(None)] * self.ndim
-            idx[a] = 0
-            m[tuple(idx)] = True
-            idx[a] = self.ext[a] - 1
-            m[tuple(idx)] = True
-        return m
 
     def boundary_pos(self, g, xp=jnp):
         """Map global flat ids to their canonical slot in the gathered
@@ -285,23 +279,6 @@ _check_table_mode = check_table_mode  # shared with the graph backend
 # --- shared traced helpers ---------------------------------------------------
 
 
-def _pad_input(x, dec: BlockDecomp, fill):
-    """Pad a global input up to the statically padded grid (deviation (p)):
-    `fill` must be the phase's inert sentinel (order -1 / mask False), so
-    padding can never win a steepest/mask argmax."""
-    if not dec.ragged:
-        return x
-    pads = [(0, dec.padded[i] - dec.grid[i]) for i in range(dec.ndim)]
-    return jnp.pad(x, pads, constant_values=fill)
-
-
-def _unpad_output(x, dec: BlockDecomp):
-    """Slice a padded global output back to the real grid extent."""
-    if not dec.ragged:
-        return x
-    return x[tuple(slice(0, g) for g in dec.grid)]
-
-
 def _owned_valid(dec: BlockDecomp):
     """Boolean owned-block array marking in-domain (non-pad) cells, from the
     block's position on the mesh (deviation (p) in DESIGN.md)."""
@@ -336,23 +313,23 @@ def _halo_extend(ext, dim, name, n_blocks, fill):
     return jnp.concatenate([lo, ext, hi], axis=dim)
 
 
-def _gid_map(dec: BlockDecomp):
-    """Global flat id of every extended-block position (out-of-domain ghost
-    coordinates produce ids that are never read: their order/mask fill keeps
-    them off every pointer path)."""
-    total = None
-    for i in range(dec.ndim):
+def _to_global(d, dec: BlockDecomp):
+    """Global flat ids of extended-block local ids `d` (>= 0), by coordinate
+    arithmetic on `d` itself — no block-sized id map is built, so nothing
+    block-sized can be constant-folded into the program (out-of-domain
+    ghost coordinates give ids that are never read: their order/mask fill
+    keeps them off every pointer path)."""
+    d = d.astype(dec.id_dtype)
+    g = None
+    for i in reversed(range(dec.ndim)):
+        c = d % dec.ext[i]
+        d = d // dec.ext[i]
         if i < dec.k:
-            b = lax.axis_index(dec.names[i])
-            x = b * dec.local[i] - 1 + jnp.arange(dec.ext[i],
-                                                  dtype=dec.id_dtype)
-        else:
-            x = jnp.arange(dec.grid[i], dtype=dec.id_dtype)
-        shape = [1] * dec.ndim
-        shape[i] = -1
-        part = (x * dec.stride[i]).reshape(shape)
-        total = part if total is None else total + part
-    return total
+            b = lax.axis_index(dec.names[i]).astype(dec.id_dtype)
+            c = b * dec.local[i] - 1 + c
+        part = c * dec.stride[i]
+        g = part if g is None else g + part
+    return g
 
 
 def _gather_table(owned, dec: BlockDecomp):
@@ -616,7 +593,7 @@ def _sharded_manifold_resolve(owned, dec: BlockDecomp, connectivity,
 
     o = owned.ravel()
     is_b, s = dec.boundary_pos(jnp.clip(o, 0), jnp)
-    okp, idx = geom.pos_to_stack(s)
+    is_b, okp, idx = materialize((is_b, *geom.pos_to_stack(s)))
     final = jnp.where((o >= 0) & is_b & okp,
                       stackT[jnp.clip(idx, 0, geom.stack_size - 1)], o)
     return final, geom, rounds, iters, ok
@@ -636,23 +613,19 @@ def _manifold_block(order_blk, *, dec: BlockDecomp, connectivity,
     for a in range(dec.k):
         ext = _halo_extend(ext, a, dec.names[a], dec.layout[a], -1)
 
-    # 2.+3a. fused steepest init + in-tile saturation in local ids, ghosts
-    #    pretending to be maxima (Alg. 1 lines 6-8); on the jnp fallback this
-    #    is exactly the unfused init (kernel_rounds == 0)
-    d, kernel_rounds = fused_local_phase(
-        ext, connectivity, mode="manifold",
-        self_mask=jnp.asarray(dec.ghost_mask()), impl=fused_impl)
-    d = d.ravel()
+    # 2. steepest init in local ids, the ghost layers (first/last layer of
+    #    every decomposed axis) pretending to be maxima (Alg. 1 lines 6-8)
+    d = fused_local_phase(ext, connectivity, mode="manifold",
+                          ghost_axes=tuple(range(dec.k)),
+                          impl=fused_impl).ravel()
 
-    # 3. local compression to the block fixpoint (Alg. 1 lines 9-19; with
-    #    the kernel path it starts near-converged — only chains crossing
-    #    tile boundaries remain)
+    # 3. local compression to the block fixpoint (Alg. 1 lines 9-19)
     d, local_iters = path_compress(d)
 
     # 4. to global ids + the single communication phase (Alg. 2); pad cells
     #    of a ragged block carry the sentinel -1, which the chase fixes and
     #    the substitution skips (deviation (p) in DESIGN.md)
-    owned = _gid_map(dec).ravel()[d].reshape(dec.ext)[dec.owned_slices]
+    owned = _to_global(d.reshape(dec.ext)[dec.owned_slices], dec)
     if dec.ragged:
         owned = jnp.where(_owned_valid(dec), owned, dec.id_dtype(-1))
     isz = np.dtype(dec.id_dtype).itemsize
@@ -665,7 +638,7 @@ def _manifold_block(order_blk, *, dec: BlockDecomp, connectivity,
 
         # 6. final substitution (Alg. 2 lines 27-33)
         o = owned.ravel()
-        is_b, pos = dec.boundary_pos(jnp.clip(o, 0), jnp)
+        is_b, pos = materialize(dec.boundary_pos(jnp.clip(o, 0), jnp))
         final = jnp.where((o >= 0) & is_b,
                           T[jnp.clip(pos, 0, T.size - 1)], o)
         comm = jnp.int32(1)
@@ -685,20 +658,14 @@ def _manifold_block(order_blk, *, dec: BlockDecomp, connectivity,
             jnp.float32)
         table_bytes = jnp.float32((geom.stack_size + geom.rows) * isz)
 
-    li = lax.pmax(local_iters, dec.names)
-    kr = lax.pmax(kernel_rounds, dec.names)
     stats = DPCStats(
-        local_iters=li,
+        local_iters=lax.pmax(local_iters, dec.names),
         table_iters=table_iters,
         stitch_rounds=jnp.int32(0),
         ghost_bytes=ghost_bytes,
         masked_ghost_fraction=jnp.float32(1.0),
         pad_fraction=jnp.float32(dec.pad_fraction),
         comm_phases=comm,
-        kernel_rounds=kr,
-        # the unfused local loop needs >= kr rounds to resolve the same
-        # in-tile chains, the fused one used li — a provable lower bound
-        global_iters_saved=jnp.maximum(kr - li, 0),
         table_bytes_peak=table_bytes,
         exchange_rounds=exch_rounds,
         converged=converged,
@@ -722,19 +689,14 @@ def distributed_manifold(order, mesh: Mesh, connectivity: int = 6,
     are bit-identical across all choices.
     """
     _check_table_mode(table_mode)
-    dec = _decomp_for(mesh, order.shape)
+    prog = _grid_program("manifold", mesh, tuple(order.shape), False,
+                         connectivity, True, fused_impl, table_mode,
+                         table_max_iter)
     if not descending:
-        order = order.size - 1 - order  # ascending = descending on flipped order
-    order = _pad_input(order, dec, -1)  # -1: below every real order value
-    fn = partial(_manifold_block, dec=dec, connectivity=connectivity,
-                 fused_impl=fused_impl, table_mode=table_mode,
-                 table_max_iter=table_max_iter)
-    spec = P(*dec.names, *([None] * (order.ndim - dec.k)))
-    mapped = shard_map_norep(fn, mesh, (spec,),
-                             (spec, DPCStats(*([P()] * _N_STATS))))
-    labels, stats = mapped(order)
+        order = order.size - 1 - order  # ascending = descending on flipped
+    labels, stats = prog(order)
     check_converged(stats.converged, "distributed_manifold", table_max_iter)
-    return _unpad_output(labels, dec), stats
+    return labels, stats
 
 
 # --- connected components ----------------------------------------------------
@@ -749,13 +711,14 @@ def _ext_stitch(d, mask_ext, connectivity, sentinel):
     for off in neighbor_offsets(mask_ext.ndim, connectivity):
         u_label = shift_fill(dg, off, -1).ravel()
         valid = m & shift_fill(mask_ext, off, False).ravel() & (u_label >= 0)
-        tgt = jnp.where(valid, out, sentinel)
-        out = out.at[tgt].max(jnp.where(valid, u_label, -1), mode="drop")
+        tgt, val = materialize((jnp.where(valid, out, sentinel),
+                                jnp.where(valid, u_label, -1)))
+        out = out.at[tgt].max(val, mode="drop")
     return out
 
 
 def _cc_local_fixpoint(d, mask_ext, connectivity, max_rounds=64):
-    d, it0 = path_compress(d)
+    d, its0 = path_compress(d)
     sentinel = d.size
 
     def cond(s):
@@ -769,10 +732,8 @@ def _cc_local_fixpoint(d, mask_ext, connectivity, max_rounds=64):
         return nxt, jnp.any(nxt != cur), r + jnp.int32(1), its + it
 
     d, _, rounds, its = lax.while_loop(
-        cond, body, (d, jnp.asarray(True), jnp.int32(0), it0))
-    # it0 separately: the fused kernel pre-saturates exactly this first
-    # compression, so the round-saving bound compares kernel_rounds to it0
-    return d, rounds, its, it0
+        cond, body, (d, jnp.asarray(True), jnp.int32(0), its0))
+    return d, rounds, its
 
 
 def _table_propagate(Tstar, Mflat, coords, dec: BlockDecomp, connectivity,
@@ -872,7 +833,7 @@ def _sharded_cc_resolve(owned, mask_owned, coords, dec: BlockDecomp,
     # vertices, which are in the own chunk whenever the piece reaches a cut)
     o = owned.ravel()
     is_b, s = dec.boundary_pos(jnp.clip(o, 0), jnp)
-    okp, idx = geom.pos_to_stack(s)
+    is_b, okp, idx = materialize((is_b, *geom.pos_to_stack(s)))
     chased = jnp.where((o >= 0) & is_b & okp,
                        stackG[jnp.clip(idx, 0, geom.stack_size - 1)], o)
     final = value_substitute(o, chased, sorted_vals, stackG[perm])
@@ -901,21 +862,19 @@ def _cc_block(mask_blk, coords=None, *, dec: BlockDecomp, connectivity,
     for a in range(dec.k):
         ext = _halo_extend(ext, a, dec.names[a], dec.layout[a], False)
 
-    # 2.(+first compress) fused init: largest masked neighbor id, masked
-    #    ghosts pretending self, saturated in-tile by the kernel path
-    d, kernel_rounds = fused_local_phase(
-        ext, connectivity, mode="cc",
-        self_mask=jnp.asarray(dec.ghost_mask()), impl=fused_impl)
-    d = d.ravel()
+    # 2. init: largest masked neighbor id, masked ghosts pretending self
+    d = fused_local_phase(ext, connectivity, mode="cc",
+                          ghost_axes=tuple(range(dec.k)),
+                          impl=fused_impl).ravel()
 
     # 3. local CC fixpoint (stitch + compress, Alg. 3)
-    d, stitch_rounds, local_iters, it0 = _cc_local_fixpoint(
+    d, stitch_rounds, local_iters = _cc_local_fixpoint(
         d, ext, connectivity)
 
     # 4. to global ids
-    gid = _gid_map(dec).ravel()
-    dg = jnp.where(d >= 0, gid[jnp.clip(d, 0)], -1).reshape(dec.ext)
-    owned = dg[dec.owned_slices]
+    do = d.reshape(dec.ext)[dec.owned_slices]
+    owned = jnp.where(do >= 0, _to_global(jnp.clip(do, 0), dec),
+                      dec.id_dtype(-1))
     isz = np.dtype(dec.id_dtype).itemsize
 
     if table_mode == "replicated":
@@ -938,7 +897,7 @@ def _cc_block(mask_blk, coords=None, *, dec: BlockDecomp, connectivity,
         # 6. substitution: chase own label through the table, then take its
         #    group's propagated maximum (value search over the sorted table)
         o = owned.ravel()
-        is_b, pos = dec.boundary_pos(jnp.clip(o, 0), jnp)
+        is_b, pos = materialize(dec.boundary_pos(jnp.clip(o, 0), jnp))
         chased = jnp.where((o >= 0) & is_b,
                            Tstar[jnp.clip(pos, 0, Tstar.size - 1)], o)
         final = value_substitute(o, chased, sorted_vals, G[perm])
@@ -976,8 +935,6 @@ def _cc_block(mask_blk, coords=None, *, dec: BlockDecomp, connectivity,
 
     # pad table slots are label -1 / mask False by construction (the input
     # mask is padded False, deviation (p)), so they are excluded here
-    kr = lax.pmax(kernel_rounds, dec.names)
-    i0 = lax.pmax(it0, dec.names)
     stats = DPCStats(
         local_iters=lax.pmax(local_iters, dec.names),
         table_iters=table_iters,
@@ -986,10 +943,6 @@ def _cc_block(mask_blk, coords=None, *, dec: BlockDecomp, connectivity,
         masked_ghost_fraction=masked_frac,
         pad_fraction=jnp.float32(dec.pad_fraction),
         comm_phases=comm,
-        kernel_rounds=kr,
-        # the kernel pre-saturates the FIRST compression only; the unfused
-        # first compression needs >= kr rounds, the fused one used i0
-        global_iters_saved=jnp.maximum(kr - i0, 0),
         table_bytes_peak=table_bytes,
         exchange_rounds=exch_rounds,
         converged=converged,
@@ -1012,18 +965,13 @@ def distributed_connected_components(mask, mesh: Mesh, connectivity: int = 6,
     distributed (deviation (s)).  Labels are bit-identical across all
     choices."""
     _check_table_mode(table_mode)
-    dec = _decomp_for(mesh, mask.shape)
-    mask = _pad_input(mask, dec, False)  # padding is never masked
-    fn = partial(_cc_block, dec=dec, connectivity=connectivity,
-                 gather_mask=gather_mask, fused_impl=fused_impl,
-                 table_mode=table_mode, table_max_iter=table_max_iter)
-    spec = P(*dec.names, *([None] * (mask.ndim - dec.k)))
-    mapped = shard_map_norep(fn, mesh, (spec, P(None, None)),
-                             (spec, DPCStats(*([P()] * _N_STATS))))
-    labels, stats = mapped(mask, dec.boundary_coords_dev)
+    prog = _grid_program("cc", mesh, tuple(mask.shape), False, connectivity,
+                         gather_mask, fused_impl, table_mode, table_max_iter)
+    labels, stats = prog(mask, _decomp_for(mesh, mask.shape)
+                         .boundary_coords_dev)
     check_converged(stats.converged, "distributed_connected_components",
                     table_max_iter)
-    return _unpad_output(labels, dec), stats
+    return labels, stats
 
 
 # --- batched (multi-tenant) entry points --------------------------------------
@@ -1035,27 +983,62 @@ def distributed_connected_components(mask, mesh: Mesh, connectivity: int = 6,
 # returned DPCStats carry a leading (B,) request dim.
 
 
-def _pad_input_batch(x, dec: BlockDecomp, fill):
-    """`_pad_input` for a (B, *grid) stack (grid axes shifted right by 1)."""
-    if not dec.ragged:
-        return x
-    pads = [(0, 0)] + [(0, dec.padded[i] - dec.grid[i])
-                       for i in range(dec.ndim)]
-    return jnp.pad(x, pads, constant_values=fill)
+@lru_cache(maxsize=64)
+def _grid_program(kind, mesh: Mesh, grid, batched, connectivity,
+                  gather_mask, fused_impl, table_mode, table_max_iter):
+    """One jitted SPMD program per (query kind, mesh, grid extent, knobs),
+    shared by every call with the same key (both manifold directions: the
+    callers flip the order field for ascending ones): the pad-and-mask
+    input padding (deviation (p)), the `shard_map` of the per-block program
+    and the unpadding all compile together.  ``batched`` vmaps the block
+    program over a leading request dim (stats then carry a (B,) dim).  The
+    cc program takes the boundary slot-coordinate table as a second,
+    replicated argument."""
+    dec = _decomp_for(mesh, grid)
+    if kind == "manifold":
+        fn = partial(_manifold_block, dec=dec, connectivity=connectivity,
+                     fused_impl=fused_impl, table_mode=table_mode,
+                     table_max_iter=table_max_iter)
+        fill, extra = -1, ()           # -1: below every real order value
+    else:
+        fn = partial(_cc_block, dec=dec, connectivity=connectivity,
+                     gather_mask=gather_mask, fused_impl=fused_impl,
+                     table_mode=table_mode, table_max_iter=table_max_iter)
+        fill, extra = False, (P(None, None),)   # padding is never masked
 
+    def mapped(lead):
+        spec = P(*lead, *dec.names, *([None] * (dec.ndim - dec.k)))
+        f = fn if not lead else jax.vmap(fn, in_axes=(0,) + (None,) * len(
+            extra))
+        return shard_map_norep(f, mesh, (spec,) + extra,
+                               (spec, DPCStats(*([P(*lead)] * _N_STATS))))
 
-def _batched_block_call(fn, mesh, dec: BlockDecomp, x, extra=()):
-    """`extra` holds replicated non-batched args (e.g. the slot-coordinate
-    table), broadcast across both the request dim and the mesh."""
-    spec = P(None, *dec.names, *([None] * (x.ndim - 1 - dec.k)))
-    especs = tuple(P(*([None] * np.ndim(e))) for e in extra)
-    vfn = jax.vmap(fn, in_axes=(0,) + (None,) * len(extra))
-    mapped = shard_map_norep(vfn, mesh, (spec,) + especs,
-                             (spec, DPCStats(*([P(None)] * _N_STATS))))
-    labels, stats = mapped(x, *extra)
-    if dec.ragged:
-        labels = labels[(slice(None),) + tuple(slice(0, g) for g in dec.grid)]
-    return labels, stats
+    one = mapped(())
+    many = mapped((None,)) if batched else None
+    lead_axes = 1 if batched else 0
+
+    def run(x, *args):
+        if dec.ragged:
+            pads = [(0, 0)] * lead_axes + [(0, dec.padded[i] - dec.grid[i])
+                                           for i in range(dec.ndim)]
+            x = jnp.pad(x, pads, constant_values=fill)
+        if not batched:
+            labels, stats = one(x, *args)
+        elif x.shape[0] == 1:
+            # a batch of one runs the unbatched program: vmap over a
+            # single item buys nothing, and the vmapped cc block (its
+            # while loops around scatters) takes the TPU compiler ~10x
+            # longer
+            labels, stats = jax.tree.map(lambda a: a[None],
+                                         one(x[0], *args))
+        else:
+            labels, stats = many(x, *args)
+        if dec.ragged:
+            labels = labels[(slice(None),) * lead_axes
+                            + tuple(slice(0, g) for g in dec.grid)]
+        return labels, stats
+
+    return jax.jit(run)
 
 
 def distributed_manifold_batch(orders, mesh: Mesh, connectivity: int = 6,
@@ -1067,14 +1050,12 @@ def distributed_manifold_batch(orders, mesh: Mesh, connectivity: int = 6,
     fields sharing one extent; returns ((B, *grid) labels, DPCStats with a
     leading (B,) dim).  Per item bit-identical to the single-request call."""
     _check_table_mode(table_mode)
-    dec = _decomp_for(mesh, orders.shape[1:])
+    grid = tuple(orders.shape[1:])
+    prog = _grid_program("manifold", mesh, grid, True, connectivity, True,
+                         fused_impl, table_mode, table_max_iter)
     if not descending:
-        orders = dec.size - 1 - orders  # ascending = descending on flipped
-    orders = _pad_input_batch(orders, dec, -1)
-    fn = partial(_manifold_block, dec=dec, connectivity=connectivity,
-                 fused_impl=fused_impl, table_mode=table_mode,
-                 table_max_iter=table_max_iter)
-    labels, stats = _batched_block_call(fn, mesh, dec, orders)
+        orders = math.prod(grid) - 1 - orders  # ascending: flipped order
+    labels, stats = prog(orders)
     check_converged(stats.converged, "distributed_manifold_batch",
                     table_max_iter)
     return labels, stats
@@ -1091,13 +1072,10 @@ def distributed_connected_components_batch(masks, mesh: Mesh,
     DPCStats with a leading (B,) dim).  Per item bit-identical to the
     single-request call."""
     _check_table_mode(table_mode)
-    dec = _decomp_for(mesh, masks.shape[1:])
-    masks = _pad_input_batch(masks, dec, False)
-    fn = partial(_cc_block, dec=dec, connectivity=connectivity,
-                 gather_mask=gather_mask, fused_impl=fused_impl,
-                 table_mode=table_mode, table_max_iter=table_max_iter)
-    labels, stats = _batched_block_call(fn, mesh, dec, masks,
-                                        extra=(dec.boundary_coords_dev,))
+    grid = tuple(masks.shape[1:])
+    prog = _grid_program("cc", mesh, grid, True, connectivity, gather_mask,
+                         fused_impl, table_mode, table_max_iter)
+    labels, stats = prog(masks, _decomp_for(mesh, grid).boundary_coords_dev)
     check_converged(stats.converged, "distributed_connected_components_batch",
                     table_max_iter)
     return labels, stats

@@ -512,8 +512,6 @@ def _cc_partition(local_mask, lgid, local_ghost, owned_lidx, es, er,
         masked_ghost_fraction=masked_frac,
         comm_phases=comm,
         pad_fraction=jnp.float32(dec.pad_fraction),
-        kernel_rounds=jnp.int32(0),        # no fused grid kernel on graphs
-        global_iters_saved=jnp.int32(0),
         table_bytes_peak=table_bytes,
         exchange_rounds=exch_rounds,
         converged=converged,

@@ -10,24 +10,47 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
+
+
+def _sort_key(flat: jax.Array) -> jax.Array:
+    """int32 key whose signed order is the order `lax.sort` gives `flat`:
+    zeros and NaNs canonicalised first (so -0.0 ties 0.0 and NaNs sort
+    last), then the float's bits with the magnitude bits flipped for
+    negatives.  Integer fields of 32 bits or fewer map directly."""
+    if jnp.issubdtype(flat.dtype, jnp.floating):
+        f = flat.astype(jnp.float32)
+        f = jnp.where(f == 0, jnp.float32(0), f)
+        f = jnp.where(jnp.isnan(f), jnp.float32(jnp.nan), f)
+        b = lax.bitcast_convert_type(f, jnp.int32)
+        return b ^ ((b >> 31) & jnp.int32(0x7FFFFFFF))
+    if flat.dtype == jnp.uint32:
+        return lax.bitcast_convert_type(flat ^ jnp.uint32(1 << 31),
+                                        jnp.int32)
+    return flat.astype(jnp.int32)
 
 
 def compute_order(scalars: jax.Array, ids: jax.Array | None = None) -> jax.Array:
     """Global order field: rank of each vertex under (scalar, id) lexsort.
 
     Mirrors TTK's ttkArrayPreconditioning (paper §4.1).  Returns int32 ranks
-    in [0, N) — a permutation, hence injective.
+    in [0, N) — a permutation, hence injective.  The sort is one unstable
+    int32 sort over (scalar key, id[, position]) — unique keys, so it is
+    the stable lexsort's permutation bit for bit, and it compiles for a TPU
+    several times faster than a stable float sort (DESIGN.md §Perf).
+    float64 / 64-bit integer scalars (x64 only) keep the float lexsort.
     """
     flat = scalars.ravel()
     n = flat.shape[0]
-    if ids is None:
-        ids = jnp.arange(n, dtype=jnp.int32)
+    pos = jnp.arange(n, dtype=jnp.int32)
+    if flat.dtype.itemsize > 4:
+        perm = jnp.lexsort((pos if ids is None else ids.ravel(), flat))
     else:
-        ids = ids.ravel()
-    perm = jnp.lexsort((ids, flat))  # stable: primary scalar, tie-break id
+        keys = (_sort_key(flat),) + (() if ids is None else (ids.ravel(),))
+        perm = lax.sort(keys + (pos,), num_keys=len(keys) + 1,
+                        is_stable=False)[-1]
     order = jnp.zeros(n, dtype=jnp.int32).at[perm].set(
-        jnp.arange(n, dtype=jnp.int32)
-    )
+        pos, unique_indices=True)
     return order.reshape(scalars.shape)
 
 

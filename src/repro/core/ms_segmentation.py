@@ -25,14 +25,12 @@ class MSSegmentation(NamedTuple):
 
 
 def _fused_init(order, connectivity, fused_impl):
-    """Block-local phase through the kernels dispatch (lazy import:
-    repro.kernels imports repro.core.steepest at module load).  Returns the
-    (possibly pre-saturated) pointer init; the path_compress fixpoint is
-    bit-identical to the plain grid_steepest init."""
+    """Pointer init through the kernels dispatch (lazy import:
+    repro.kernels imports repro.core.steepest at module load); bit-identical
+    to the plain grid_steepest init on every implementation."""
     from repro.kernels.ops import fused_local_phase
-    d0, _ = fused_local_phase(order, connectivity, mode="manifold",
-                              impl=fused_impl)
-    return d0.ravel()
+    return fused_local_phase(order, connectivity, mode="manifold",
+                             impl=fused_impl).ravel()
 
 
 def descending_manifold(order: jax.Array, connectivity: int = 6,

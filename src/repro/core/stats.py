@@ -25,7 +25,7 @@ import jax
 # the shared field prefix, in the canonical order both classes use
 STAT_FIELDS = ("local_iters", "table_iters", "stitch_rounds", "ghost_bytes",
                "masked_ghost_fraction", "pad_fraction", "comm_phases",
-               "kernel_rounds", "global_iters_saved", "table_bytes_peak",
+               "table_bytes_peak",
                "exchange_rounds", "converged")
 
 
@@ -53,13 +53,6 @@ class DPCStats(NamedTuple):
     comm_phases: jax.Array      # bulk exchange phases traced (paper budget:
                                 # 1; the halo ppermute is ghost setup, not a
                                 # gather phase)
-    kernel_rounds: jax.Array    # max in-tile saturation rounds of the fused
-                                # local-phase kernel (0 on the jnp fallback)
-    global_iters_saved: jax.Array  # provable lower bound on doubling rounds
-                                   # the fusion removed from the global loop:
-                                   # max(kernel_rounds - local_iters, 0) —
-                                   # the unfused loop needs >= kernel_rounds
-                                   # rounds to resolve the same chains
     table_bytes_peak: jax.Array    # per-device bytes materialized for the
                                    # boundary-table resolution (replicated:
                                    # the full gathered table; sharded: own
@@ -86,9 +79,6 @@ class GraphDPCStats(NamedTuple):
     pad_fraction: jax.Array     # fraction of owned slots that are padding
                                 # (0 for a balanced partition)
     comm_phases: jax.Array      # all_gather phases traced (paper budget: 1)
-    kernel_rounds: jax.Array    # always 0: the fused grid kernel does not
-                                # apply to unstructured partitions
-    global_iters_saved: jax.Array  # always 0 (see kernel_rounds)
     table_bytes_peak: jax.Array    # per-device bytes materialized for the
                                    # cut-table resolution (replicated: full
                                    # gathered table (+mask); sharded: own

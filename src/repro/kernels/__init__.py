@@ -1,8 +1,9 @@
 """Pallas TPU kernels for the perf-critical hot spots, with jnp oracles.
 
   steepest_neighbor  — DPC init stencil (Alg. 1 l. 3-5), VMEM-tiled argmax
-  fused_local_phase  — init + in-tile doubling saturation in ONE kernel
-                       (the block-local phase of Alg. 1/3; DESIGN.md §Perf)
+  fused_local_phase  — pointer init + ghost override in ONE kernel (the
+                       block-local phase of Alg. 1/3; the one kernel on a
+                       topology path, dispatched by ops.py)
   block_pathcompress — K in-VMEM doubling rounds (thread-local compression)
   flash_attention    — fused online-softmax attention for the LM substrate
   segment_bag        — fused EmbeddingBag (vocab-tiled gather+reduce),

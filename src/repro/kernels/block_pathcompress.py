@@ -66,7 +66,7 @@ def _padded_call(d: jax.Array, rounds: int, block: int,
 
 
 def block_pathcompress(d: jax.Array, rounds: int = 4, block: int = 4096,
-                       interpret: bool = True) -> jax.Array:
+                       *, interpret: bool) -> jax.Array:
     """K pointer-doubling rounds confined to `block`-sized tiles.
 
     d: (N,) int32 global pointers (any N; ragged tiles are padded with the

@@ -60,7 +60,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 @functools.partial(
     jax.jit, static_argnames=("causal", "block_q", "block_k", "interpret"))
 def flash_attention(q, k, v, causal: bool = False, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = True):
+                    block_k: int = 128, *, interpret: bool):
     """q: (B, H, Sq, D); k, v: (B, Hkv, Skv, D) with H % Hkv == 0.
 
     Returns (B, H, Sq, D).  GQA is handled by an index-map trick: kv blocks

@@ -1,37 +1,35 @@
-"""Pallas TPU kernel: fused block-local phase (init + in-VMEM saturation).
+"""Pallas TPU kernel: fused block-local pointer init (DPC Alg. 1 l. 3-8).
 
-The block-local phase of Alg. 1/3 is DPC's hot path, and running it as
-`grid_steepest` followed by a global `d <- d[d]` while-loop costs one full
-HBM round-trip per doubling round, with the extended block materialised
-between init and first compression.  This kernel fuses both: per
-VMEM-resident x-slab it
+The block-local phase of Alg. 1/3 starts with a stencil pass over the
+extended block: every vertex points at its steepest neighbor
+(``mode="manifold"``: the argmax of the order field over itself and the
+stencil) or at the largest masked neighbor id (``mode="cc"``: -1 where
+unmasked), and the ghost layers of a distributed block pretend to be
+maxima / roots (Alg. 1 lines 6-8).  This kernel does all of it in one HBM
+read and one HBM write per vertex; the pointer doubling that follows is the
+global `path_compress` loop.
 
-  1. computes the pointer init directly from the order field (steepest
-     argmax, ``mode="manifold"``) or the feature mask (largest masked
-     neighbor id, ``mode="cc"``), reusing the pre-sliced halo-plane layout
-     of `steepest_neighbor` (no overlapping BlockSpecs);
-  2. applies the optional ``self_mask`` override in-register (distributed
-     ghost vertices pretend to be maxima, Alg. 1 lines 6-8);
-  3. runs the pointer-doubling saturation loop *inside the tile* until the
-     tile is locally converged (the on-device saturation-loop idiom of the
-     GPU Morse-Smale pipeline, arXiv 2009.03707).
+Tiling (Mosaic): the grid is (x tiles, y tiles) over a ``(bx, by, Z)``
+block with the whole z extent in the lane axis.
 
-Out-of-tile and sentinel (-1) pointers are fixed points, so the tile
-boundary is a ghost boundary and correctness follows from the distributed
-algorithm's own argument (DESIGN.md §Perf): the fixpoint of pointer chasing
-is invariant under restricted jumps, and the remaining *global* doubling
-loop starts near-converged.  One HBM read + one write per voxel buys all
-intra-tile rounds.
+* ``bx`` is a leading (untiled) dimension: any size; a ragged last tile is
+  fine because every neighbor read is masked by its global coordinate.
+* ``by`` is either the whole y extent or a multiple of 8 that divides it
+  (the sublane tiling).  Neighbors one row past the tile come from two
+  extra ``(bx, 8, Z)`` reads of the same array (rows 7 / 0 of the adjacent
+  8-row groups), so no input is ever re-laid-out in HBM.
+* x neighbors come from two ``(1, by, Z)`` plane reads; stencils with an
+  x/y diagonal (connectivity 14/18/26) also read the four ``(1, 8, Z)``
+  corner groups.
+* Shifts inside the tile are `pltpu.roll` rotations; the argmax is a
+  compare-and-select chain in int32 (self first, then the stencil in table
+  order, strict ``>`` — the same first-max-wins rule as `grid_steepest`).
+* The ghost override is built from iota comparisons, never from an input
+  array.
 
-Slab extents need not divide the tile: the x axis is padded up to the tile
-grid with an inert fill (order ``iinfo.min`` / mask ``False``) that can
-never win an argmax, so pad rows self-point and are sliced back off
-(pad-and-mask, deviation (p) in DESIGN.md).
-
-Returns ``(pointers, rounds)``: pointers are flat ids of the input array
-(same local-id convention as `grid_steepest`; ``-1`` for unmasked CC
-vertices), rounds is the max in-tile saturation round count over slabs —
-surfaced as ``DPCStats.kernel_rounds`` by the distributed entry points.
+There is no in-tile pointer doubling: it needs a 1-D dynamic gather, which
+Mosaic does not lower.  On the CPU the kernel runs in interpret mode; the
+caller chooses (`interpret=` has no default).
 """
 from __future__ import annotations
 
@@ -39,119 +37,159 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.steepest import neighbor_offsets
 
 # connectivities with a 3-D offset table (the kernel is 3-D only; ops.py
-# dispatches every other case to the jnp fallback)
+# sends every other case to the jnp init)
 KERNEL_CONNECTIVITIES = (6, 14, 18, 26)
 
-
-def _shifted(a, off, fill_val):
-    """a[p + off] within the tile, fill outside (static shifts)."""
-    pads = [(max(-o, 0), max(o, 0)) for o in off]
-    padded = jnp.pad(a, pads, constant_values=fill_val)
-    sl = tuple(slice(max(o, 0), max(o, 0) + s)
-               for o, s in zip(off, a.shape))
-    return padded[sl]
+# target elements per (bx, by, Z) tile: 512 KiB of int32, which keeps the
+# double-buffered inputs plus the kernel's handful of live tiles well inside
+# the default scoped VMEM
+_TILE_ELEMS = 1 << 17
 
 
-def _kernel(center, lo, hi, *rest, offsets, block_x, R, fill, mode,
-            max_rounds, id_dtype, has_self_mask, n_real):
-    if has_self_mask:
-        smask_ref, out_ref, rounds_ref = rest
+def choose_tile(shape):
+    """(bx, by) for a field of `shape` (see the module docstring)."""
+    x, y, z = shape
+    if y % 8:
+        by = y
     else:
-        out_ref, rounds_ref = rest
-    i = pl.program_id(0)
-    ext = jnp.concatenate([lo[...], center[...], hi[...]], axis=0)
-    z = ext.shape[2]
-    # flat ids of the extended tile in the (padded) input array (row-major,
-    # x-major layout); the lo plane sits at global x = i*block_x - 1
-    base = (i * block_x - 1).astype(id_dtype) * R
-    gids = base + jax.lax.broadcasted_iota(id_dtype, ext.shape, 0) * R \
-        + jax.lax.broadcasted_iota(id_dtype, ext.shape, 1) * z \
-        + jax.lax.broadcasted_iota(id_dtype, ext.shape, 2)
+        fits = [d for d in range(8, y + 1, 8)
+                if y % d == 0 and d * z * 8 <= _TILE_ELEMS]
+        by = max(fits) if fits else 8
+    bx = max(1, min(x, _TILE_ELEMS // (by * z)))
+    return bx, by
+
+
+def _xshift(center, lo, hi, dx):
+    """Planes x+dx of the tile: `center` (bx, ...) with the plane before
+    (`lo`) / after (`hi`) it, each (1, ...)."""
+    if dx == 0:
+        return center
+    if center.shape[0] == 1:
+        return hi if dx > 0 else lo
+    if dx > 0:
+        return jnp.concatenate([center[1:], hi], axis=0)
+    return jnp.concatenate([lo, center[:-1]], axis=0)
+
+
+def _kernel(*refs, offsets, shape, bx, by, mode, fill, ghost_axes,
+            y_halo, corners, id_dtype):
+    c_ref, xlo_ref, xhi_ref = refs[:3]
+    rest = refs[3:]
+    if y_halo:
+        ylo_ref, yhi_ref = rest[:2]
+        rest = rest[2:]
+        if corners:
+            ll_ref, lh_ref, hl_ref, hh_ref = rest[:4]
+            rest = rest[4:]
+    (out_ref,) = rest
+    X, Y, Z = shape
+    R = Y * Z
+    i, j = pl.program_id(0), pl.program_id(1)
+
+    c = c_ref[...]
+    tshape = c.shape
+    gx = i * bx + jax.lax.broadcasted_iota(jnp.int32, tshape, 0)
+    ly = jax.lax.broadcasted_iota(jnp.int32, tshape, 1)
+    gy = j * by + ly
+    gz = jax.lax.broadcasted_iota(jnp.int32, tshape, 2)
+    gid = (gx.astype(id_dtype) * R + gy.astype(id_dtype) * Z
+           + gz.astype(id_dtype))
+
+    planes = {dx: _xshift(c, xlo_ref[...], xhi_ref[...], dx)
+              for dx in (-1, 0, 1)}
+    if y_halo:
+        # row y0-1 / y0+by of each x-shifted plane: row 7 of the 8-row
+        # group above, row 0 of the group below
+        ylo, yhi = ylo_ref[:, 7:8, :], yhi_ref[:, 0:1, :]
+        if corners:
+            rows = {-1: {dx: _xshift(ylo, ll_ref[:, 7:8, :],
+                                     hl_ref[:, 7:8, :], dx)
+                         for dx in (-1, 0, 1)},
+                    1: {dx: _xshift(yhi, lh_ref[:, 0:1, :],
+                                    hh_ref[:, 0:1, :], dx)
+                        for dx in (-1, 0, 1)}}
+        else:
+            rows = {-1: {0: ylo}, 1: {0: yhi}}
+
+    def neighbor(dx, dy, dz):
+        """(value at p + (dx, dy, dz), in-domain flag); out-of-tile rows
+        come from the halo reads, out-of-domain cells are flagged."""
+        v = planes[dx]
+        valid = None
+        if dy:
+            v = pltpu.roll(v, (-dy) % by, 1)
+            if y_halo:
+                edge = by - 1 if dy > 0 else 0
+                v = jnp.where(ly == edge,
+                              jnp.broadcast_to(rows[dy][dx], tshape), v)
+        if dz:
+            v = pltpu.roll(v, (-dz) % Z, 2)
+        for g, d, n in ((gx, dx, X), (gy, dy, Y), (gz, dz, Z)):
+            if d:
+                ok = (g + d >= 0) & (g + d < n)
+                valid = ok if valid is None else valid & ok
+        return v, valid
 
     minus1 = jnp.asarray(-1, id_dtype)
     if mode == "manifold":
-        # stacked candidates + ONE argmax, not a chain of per-offset selects
-        # — the chained-where form sends XLA:CPU fusion into minutes-long
-        # compiles at connectivity >= 14 (same pathology grid_steepest works
-        # around).  Self is candidate 0, so argmax's first-max-wins tie rule
-        # keeps self on ties, which only occur at the inert fill value.
-        cand_val = jnp.stack([ext] + [_shifted(ext, off, fill)
-                                      for off in offsets])
-        cand_idx = jnp.stack([gids] + [_shifted(gids, off, minus1)
-                                       for off in offsets])
-        choice = jnp.argmax(cand_val, axis=0)
-        ptr = jnp.take_along_axis(cand_idx, choice[None], axis=0)[0][1:-1]
-        # ragged-pad rows (ids past the real extent) sit BELOW every real
-        # order value, so they'd point into the real region and burn chase
-        # rounds; pin them to self — inert fixed points, sliced off outside
-        own = gids[1:-1]
-        ptr = jnp.where(own < n_real, ptr, own)
+        best_v, ptr = c, gid
+        for off in offsets:
+            v, valid = neighbor(*off)
+            v = jnp.where(valid, v, fill)
+            better = v > best_v
+            best_v = jnp.where(better, v, best_v)
+            delta = off[0] * R + off[1] * Z + off[2]
+            ptr = jnp.where(better, gid + delta, ptr)
         masked = None
     else:  # "cc": largest masked neighbor id (incl. self), -1 unmasked
-        key = jnp.where(ext != 0, gids, minus1)
-        best = key
+        masked = c != 0
+        best = jnp.where(masked, gid, minus1)
         for off in offsets:
-            best = jnp.maximum(best, _shifted(key, off, minus1))
-        masked = ext[1:-1] != 0
-        ptr = jnp.where(masked, best[1:-1], minus1)
+            v, valid = neighbor(*off)
+            delta = off[0] * R + off[1] * Z + off[2]
+            cand = jnp.where(valid & (v != 0), gid + delta, minus1)
+            best = jnp.maximum(best, cand)
+        ptr = jnp.where(masked, best, minus1)
 
-    if has_self_mask:
-        # ghost override: (masked) ghosts pretend to be maxima / roots
-        keep = smask_ref[...] != 0
+    if ghost_axes:
+        # ghost layers (first/last index along each decomposed axis)
+        # pretend to be maxima / roots — Alg. 1 lines 6-8
+        keep = None
+        for a in ghost_axes:
+            g = (gx, gy, gz)[a]
+            on = (g == 0) | (g == shape[a] - 1)
+            keep = on if keep is None else keep | on
         if masked is not None:
             keep = keep & masked
-        ptr = jnp.where(keep, gids[1:-1], ptr)
-
-    # in-tile saturation: doubling rounds confined to this slab's id range;
-    # out-of-tile and negative pointers are fixed points (ghost boundary)
-    tsize = block_x * R
-    base_c = (i * block_x).astype(id_dtype) * R
-    d0 = ptr.reshape(-1)
-
-    def cond(state):
-        _, changed, r = state
-        return changed & (r < max_rounds)
-
-    def body(state):
-        d, _, r = state
-        local = d - base_c
-        in_tile = (d >= 0) & (local >= 0) & (local < tsize)
-        idx = jnp.clip(local, 0, tsize - 1).astype(jnp.int32)
-        nd = jnp.take(d, idx, axis=0)
-        nxt = jnp.where(in_tile, nd, d)
-        return nxt, jnp.any(nxt != d), r + jnp.int32(1)
-
-    d, _, rounds = lax.while_loop(
-        cond, body, (d0, jnp.asarray(True), jnp.int32(0)))
-    out_ref[...] = d.reshape(ptr.shape)
-    rounds_ref[...] = jnp.full((1,), rounds, jnp.int32)
+        ptr = jnp.where(keep, gid, ptr)
+    out_ref[...] = ptr
 
 
-@functools.partial(jax.jit, static_argnames=("connectivity", "mode",
-                                             "block_x", "interpret",
-                                             "id_dtype"))
+@functools.partial(jax.jit, static_argnames=(
+    "connectivity", "mode", "ghost_axes", "interpret", "tile", "id_dtype"))
 def fused_local_phase(field: jax.Array, connectivity: int = 6,
-                      mode: str = "manifold", self_mask=None,
-                      block_x: int = 8, interpret: bool = True,
-                      id_dtype=None):
-    """Fused steepest/mask-argmax init + in-tile saturation per x-slab.
+                      mode: str = "manifold", ghost_axes: tuple = (), *,
+                      interpret: bool, tile=None, id_dtype=None):
+    """Steepest / mask-argmax pointer init with the ghost override.
 
-    field: (X, Y, Z) int order field (``mode="manifold"``; unique values,
-    any inert fill strictly below them) or bool/int feature mask
-    (``mode="cc"``).  self_mask: optional (X, Y, Z) bool — positions forced
-    to self-pointers in the init (the distributed ghost layer).  Returns
-    ((X, Y, Z) flat-id pointers, int32 max in-tile rounds).
+    field: (X, Y, Z) int order field (``mode="manifold"``: unique values,
+    any inert fill strictly above ``iinfo.min``) or bool/int feature mask
+    (``mode="cc"``).  ghost_axes: axes whose first and last layers are
+    ghosts (self-pointers; in cc mode only where masked).  tile: optional
+    (bx, by) override of `choose_tile`.  Returns (X, Y, Z) flat-id pointers
+    (``-1`` for unmasked cc vertices), the same contract as `grid_steepest`
+    / `grid_mask_argmax` plus the override.
     """
     if field.ndim != 3:
         raise ValueError(
-            f"fused_local_phase is a 3-D x-slab kernel; got a {field.ndim}-D "
-            f"field of shape {field.shape} — use the jnp fallback in "
+            f"fused_local_phase is a 3-D kernel; got a {field.ndim}-D "
+            f"field of shape {field.shape} — use the jnp init in "
             "repro.kernels.ops (impl='ref'), which dispatches it for you")
     if connectivity not in KERNEL_CONNECTIVITIES:
         raise ValueError(
@@ -165,60 +203,49 @@ def fused_local_phase(field: jax.Array, connectivity: int = 6,
     if id_dtype == jnp.int64 and not jax.config.jax_enable_x64:
         raise ValueError("int64 pointer ids require jax_enable_x64 "
                          "(ids would silently wrap to int32)")
+    bx, by = tile or choose_tile(field.shape)
+    if by != y and (by % 8 or y % by):
+        raise ValueError(f"tile y extent {by} must be {y} or a multiple of "
+                         f"8 dividing it")
 
     if mode == "manifold":
         key = field
         fill = jnp.iinfo(field.dtype).min
     else:
-        key = field.astype(jnp.int32)   # 0/1 mask; fill 0 = unmasked
+        key = field.astype(jnp.int32)   # 0/1 mask
         fill = 0
 
-    # ragged x extent: pad up to the tile grid with the inert fill — pad
-    # rows self-point (fill never wins an argmax) and are sliced back off
-    n_tiles = -(-x // block_x)
-    x_pad = n_tiles * block_x
-    if x_pad != x:
-        key = jnp.pad(key, [(0, x_pad - x), (0, 0), (0, 0)],
-                      constant_values=fill)
-    # pre-sliced halo planes: lo[i] = key[i*bx - 1], hi[i] = key[(i+1)*bx]
-    padded = jnp.concatenate([
-        jnp.full((1, y, z), fill, key.dtype), key,
-        jnp.full((1, y, z), fill, key.dtype)], axis=0)
-    lo = padded[0::block_x][:n_tiles]
-    hi = padded[block_x + 1::block_x][:n_tiles]
-
-    operands = [key, lo, hi]
-    in_specs = [
-        pl.BlockSpec((block_x, y, z), lambda i: (i, 0, 0)),
-        pl.BlockSpec((1, y, z), lambda i: (i, 0, 0)),
-        pl.BlockSpec((1, y, z), lambda i: (i, 0, 0)),
-    ]
-    if self_mask is not None:
-        sm = self_mask.astype(jnp.int32)
-        if x_pad != x:
-            sm = jnp.pad(sm, [(0, x_pad - x), (0, 0), (0, 0)])
-        operands.append(sm)
-        in_specs.append(pl.BlockSpec((block_x, y, z), lambda i: (i, 0, 0)))
-
-    tsize = block_x * y * z
-    # chain <= tile size, doubling resolves it in ceil(log2) rounds, plus
-    # the final no-change verification round
-    max_rounds = max((tsize - 1).bit_length(), 1) + 1
+    offsets = neighbor_offsets(3, connectivity)
+    y_halo = by != y
+    corners = y_halo and any(o[0] and o[1] for o in offsets)
+    ny8 = y // 8
+    xlo = lambda i: jnp.maximum(i * bx - 1, 0)
+    xhi = lambda i: jnp.minimum((i + 1) * bx, x - 1)
+    ylo = lambda j: jnp.maximum(j * (by // 8) - 1, 0)
+    yhi = lambda j: jnp.minimum((j + 1) * (by // 8), ny8 - 1)
+    in_specs = [pl.BlockSpec((bx, by, z), lambda i, j: (i, j, 0)),
+                pl.BlockSpec((1, by, z), lambda i, j: (xlo(i), j, 0)),
+                pl.BlockSpec((1, by, z), lambda i, j: (xhi(i), j, 0))]
+    if y_halo:
+        in_specs += [
+            pl.BlockSpec((bx, 8, z), lambda i, j: (i, ylo(j), 0)),
+            pl.BlockSpec((bx, 8, z), lambda i, j: (i, yhi(j), 0))]
+        if corners:
+            in_specs += [
+                pl.BlockSpec((1, 8, z), lambda i, j: (xlo(i), ylo(j), 0)),
+                pl.BlockSpec((1, 8, z), lambda i, j: (xlo(i), yhi(j), 0)),
+                pl.BlockSpec((1, 8, z), lambda i, j: (xhi(i), ylo(j), 0)),
+                pl.BlockSpec((1, 8, z), lambda i, j: (xhi(i), yhi(j), 0))]
     kernel = functools.partial(
-        _kernel, offsets=neighbor_offsets(3, connectivity), block_x=block_x,
-        R=y * z, fill=fill, mode=mode, max_rounds=max_rounds,
-        id_dtype=id_dtype, has_self_mask=self_mask is not None,
-        n_real=x * y * z)
-    ptr, rounds = pl.pallas_call(
+        _kernel, offsets=offsets, shape=(x, y, z), bx=bx, by=by, mode=mode,
+        fill=fill, ghost_axes=tuple(ghost_axes), y_halo=y_halo,
+        corners=corners, id_dtype=id_dtype)
+    return pl.pallas_call(
         kernel,
-        grid=(n_tiles,),
+        grid=(pl.cdiv(x, bx), y // by),
         in_specs=in_specs,
-        out_specs=[pl.BlockSpec((block_x, y, z), lambda i: (i, 0, 0)),
-                   pl.BlockSpec((1,), lambda i: (i,))],
-        out_shape=[jax.ShapeDtypeStruct((x_pad, y, z), id_dtype),
-                   jax.ShapeDtypeStruct((n_tiles,), jnp.int32)],
+        out_specs=pl.BlockSpec((bx, by, z), lambda i, j: (i, j, 0)),
+        out_shape=jax.ShapeDtypeStruct((x, y, z), id_dtype),
         interpret=interpret,
-    )(*operands)
-    if x_pad != x:
-        ptr = ptr[:x]
-    return ptr, jnp.max(rounds)
+        name=f"fused_local_phase_{mode}",
+    )(*([key] * len(in_specs)))

@@ -1,21 +1,22 @@
-"""Jit'd public wrappers for the Pallas kernels.
+"""Hot-path dispatch between the Pallas kernels and their jnp twins.
 
-On TPU the fused kernels run compiled (`interpret=False`); on CPU (this
-container, and any unit-test environment) they execute in interpret mode and
-are validated against the pure-jnp oracles in ref.py.  `impl="ref"` forces
-the oracle — the dry-run lowers models with the ref implementations so the
-HLO stays portable across backends.
+`fused_local_phase` is the one kernel on a topology path (the pointer init
+of `_manifold_block` / `_cc_block` and of the pure grid entry points).  The
+dispatch is static — decided from the backend and the input's shape, never
+from a caught failure:
+
+* on a TPU, the compiled kernel whenever it applies: a 3-D field, a
+  connectivity in `KERNEL_CONNECTIVITIES`, and int32 ids (Pallas TPU has no
+  int64, so grids of 2**31 vertices or more take the jnp init);
+* everywhere else the jnp init, which XLA fuses well on the CPU.  The
+  kernel's interpret mode is for tests only (``impl="kernel"``).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
-from . import ref
-from .steepest_neighbor import steepest_neighbor as _steepest_kernel
-from .block_pathcompress import block_pathcompress as _bpc_kernel
-from .flash_attention import flash_attention as _flash_kernel
-from .segment_bag import segment_bag as _bag_kernel
 from .fused_local_phase import (KERNEL_CONNECTIVITIES,
                                 fused_local_phase as _fused_kernel)
 
@@ -24,88 +25,59 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _grid_kernel_ok(field, connectivity: int) -> bool:
-    """The grid stencil kernels are 3-D x-slab programs; 2-D fields and
-    connectivities outside the 3-D offset table take the jnp fallback."""
-    return field.ndim == 3 and connectivity in KERNEL_CONNECTIVITIES
-
-
-def steepest_neighbor(order, connectivity: int = 6, impl: str = "auto",
-                      block_x: int = 8):
-    if (impl == "ref" or not _grid_kernel_ok(order, connectivity)
-            or (impl == "auto" and not _on_tpu())):
-        from repro.core.steepest import grid_steepest
-        return grid_steepest(order, connectivity).reshape(order.shape)
-    return _steepest_kernel(order, connectivity, block_x=block_x,
-                            interpret=not _on_tpu())
+def ghost_keep(shape, ghost_axes):
+    """Boolean array marking the first and last layer along each ghost
+    axis, built from iota comparisons (no host array reaches the trace)."""
+    keep = jnp.zeros(shape, bool)
+    for a in ghost_axes:
+        g = lax.broadcasted_iota(jnp.int32, shape, a)
+        keep = keep | (g == 0) | (g == shape[a] - 1)
+    return keep
 
 
 def fused_local_phase(field, connectivity: int = 6, mode: str = "manifold",
-                      self_mask=None, impl: str = "auto", block_x: int = 8,
+                      ghost_axes: tuple = (), impl: str = "auto",
                       id_dtype=None):
-    """Fused block-local phase: pointer init + in-tile saturation rounds.
+    """Block-local pointer init: steepest argmax (``mode="manifold"``) or
+    largest masked neighbor id (``mode="cc"``, -1 where unmasked), with the
+    first/last layer along each of `ghost_axes` forced to self-pointers
+    (the distributed ghost layer, Alg. 1 lines 6-8; in cc mode only where
+    masked).  Returns the (X, Y, Z) pointer array; every implementation
+    returns the same bits.
 
-    The hot-path dispatch used by `_manifold_block` / `_cc_block` and the
-    pure grid entry points.  Returns ``(pointers, kernel_rounds)`` with the
-    SAME final-label contract on every path: the pointer array has the same
-    chase fixpoint as the plain init, so the global `path_compress` that
-    follows converges to bit-identical labels — the kernel path just starts
-    it near-converged (DESIGN.md §Perf).
-
-    impl="auto": compiled kernel on TPU, jnp init elsewhere;
-    impl="kernel": force the kernel (interpret mode off-TPU — tests/benches);
-    impl="ref": force the jnp init (``kernel_rounds == 0``).
-    2-D fields and unsupported connectivities always fall back.
+    impl="auto": the module docstring's rule;
+    impl="kernel": force the kernel (interpret mode off-TPU — tests);
+    impl="ref": force the jnp init.
+    2-D fields and connectivities without a 3-D table always take the jnp
+    init.
     """
     if impl not in ("auto", "kernel", "ref"):
         raise ValueError(f"impl must be auto|kernel|ref, got {impl!r}")
-    use_kernel = (impl != "ref" and _grid_kernel_ok(field, connectivity)
-                  and (impl == "kernel" or _on_tpu()))
-    if use_kernel:
-        return _fused_kernel(field, connectivity, mode=mode,
-                             self_mask=self_mask, block_x=block_x,
+    if mode not in ("manifold", "cc"):
+        raise ValueError(f"mode must be 'manifold' or 'cc', got {mode!r}")
+    applies = field.ndim == 3 and connectivity in KERNEL_CONNECTIVITIES
+    if impl == "kernel" and applies:
+        return _fused_kernel(field, connectivity, mode, tuple(ghost_axes),
                              interpret=not _on_tpu(), id_dtype=id_dtype)
+    if (impl == "auto" and applies and _on_tpu() and field.size < 2**31
+            and id_dtype in (None, jnp.int32)):
+        return _fused_kernel(field, connectivity, mode, tuple(ghost_axes),
+                             interpret=False)
     from repro.core.steepest import grid_steepest, grid_mask_argmax
     if mode == "manifold":
         d0 = grid_steepest(field, connectivity)
-    elif mode == "cc":
-        d0 = grid_mask_argmax(field, connectivity)
     else:
-        raise ValueError(f"mode must be 'manifold' or 'cc', got {mode!r}")
+        d0 = grid_mask_argmax(field, connectivity)
     if id_dtype is not None:
         d0 = d0.astype(id_dtype)
-    if self_mask is not None:
-        keep = self_mask.ravel()
+    d0 = d0.reshape(field.shape)
+    if ghost_axes:
+        keep = ghost_keep(field.shape, ghost_axes)
         if mode == "cc":
-            keep = keep & (field.ravel() != 0)
-        ids = jnp.arange(field.size, dtype=d0.dtype)
+            keep = keep & (field != 0)
+        ids = lax.broadcasted_iota(d0.dtype, field.shape, 0)
+        for a in range(1, field.ndim):
+            ids = ids * field.shape[a] + lax.broadcasted_iota(
+                d0.dtype, field.shape, a)
         d0 = jnp.where(keep, ids, d0)
-    return d0.reshape(field.shape), jnp.int32(0)
-
-
-def block_pathcompress(d, rounds: int = 4, block: int = 4096,
-                       impl: str = "auto"):
-    if impl == "ref" or (impl == "auto" and not _on_tpu()):
-        return ref.block_pathcompress_ref(d, rounds)  # block = whole array
-    return _bpc_kernel(d, rounds=rounds, block=block,
-                       interpret=not _on_tpu())
-
-
-def flash_attention(q, k, v, causal: bool = False, impl: str = "auto",
-                    block_q: int = 128, block_k: int = 128):
-    if impl == "ref" or (impl == "auto" and not _on_tpu()):
-        return ref.flash_attention_ref(q, k, v, causal=causal)
-    return _flash_kernel(q, k, v, causal=causal, block_q=block_q,
-                         block_k=block_k, interpret=not _on_tpu())
-
-
-def embedding_bag(table, ids, impl: str = "auto", vocab_block: int = 2048,
-                  batch_block: int = 256):
-    """Fused EmbeddingBag.  The tiled kernel wins when batch*L sweeps a
-    meaningful fraction of the table (train/bulk shapes); sparse-read
-    serving keeps the XLA gather path."""
-    if impl == "ref" or (impl == "auto" and not _on_tpu()):
-        from repro.models.bst import embedding_bag as _ref_bag
-        return _ref_bag(table, ids)
-    return _bag_kernel(table, ids, vocab_block=vocab_block,
-                       batch_block=batch_block, interpret=not _on_tpu())
+    return d0

@@ -50,17 +50,13 @@ def block_pathcompress_ref(d: jax.Array, rounds: int, base: int = 0):
 
 
 def fused_local_phase_ref(field, connectivity: int, mode: str = "manifold",
-                          self_mask=None, block_x: int = 8, id_dtype=None):
-    """Bit-exact host-side oracle for the fused block-local kernel: pointer
-    init (steepest argmax / largest masked neighbor id) with the optional
-    self-mask override, then per-x-slab pointer doubling to the slab-local
-    fixpoint, counting rounds exactly like the kernel's while loop (the
-    final no-change verification round included).  Returns
-    ((X, Y, Z) pointers, max rounds over slabs)."""
+                          ghost_axes: tuple = (), id_dtype=None):
+    """Bit-exact host-side oracle for the fused pointer-init kernel: the
+    plain init (steepest argmax / largest masked neighbor id) with the first
+    and last layer along each of `ghost_axes` forced to self-pointers (cc:
+    only where masked).  Returns the (X, Y, Z) pointer array."""
     from repro.core.steepest import grid_steepest, grid_mask_argmax
     field = np.asarray(field)
-    x = field.shape[0]
-    R = int(np.prod(field.shape[1:]))
     n = field.size
     if id_dtype is None:
         id_dtype = jnp.int32 if n < 2**31 else jnp.int64
@@ -70,31 +66,18 @@ def fused_local_phase_ref(field, connectivity: int, mode: str = "manifold",
     else:
         d = np.asarray(grid_mask_argmax(jnp.asarray(field), connectivity))
     d = d.astype(np_dt)
-    if self_mask is not None:
-        keep = np.asarray(self_mask, bool).ravel()
-        if mode == "cc":
-            keep = keep & (field.ravel() != 0)
-        d = np.where(keep, np.arange(n, dtype=np_dt), d)
-
-    tsize = block_x * R
-    max_rounds = max((tsize - 1).bit_length(), 1) + 1
-    n_tiles = -(-x // block_x)
-    rounds_max = 0
-    for t in range(n_tiles):
-        lo = t * block_x * R
-        hi = min((t + 1) * block_x, x) * R
-        seg = d[lo:hi]
-        r, changed = 0, True
-        while changed and r < max_rounds:
-            local = seg - lo
-            in_tile = (seg >= 0) & (local >= 0) & (local < hi - lo)
-            nxt = np.where(in_tile, seg[np.clip(local, 0, hi - lo - 1)], seg)
-            changed = bool((nxt != seg).any())
-            seg, r = nxt, r + 1
-        d[lo:hi] = seg
-        rounds_max = max(rounds_max, r)
-    return (jnp.asarray(d.reshape(field.shape)),
-            jnp.int32(rounds_max))
+    keep = np.zeros(field.shape, bool)
+    for a in ghost_axes:
+        idx = [slice(None)] * field.ndim
+        idx[a] = 0
+        keep[tuple(idx)] = True
+        idx[a] = field.shape[a] - 1
+        keep[tuple(idx)] = True
+    keep = keep.ravel()
+    if mode == "cc":
+        keep = keep & (field.ravel() != 0)
+    d = np.where(keep, np.arange(n, dtype=np_dt), d)
+    return jnp.asarray(d.reshape(field.shape))
 
 
 # --- flash attention ---------------------------------------------------------

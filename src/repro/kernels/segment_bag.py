@@ -45,7 +45,7 @@ def _kernel(ids_ref, table_ref, out_ref, *, vocab_block, n_tiles):
 @functools.partial(jax.jit, static_argnames=("vocab_block", "batch_block",
                                              "interpret"))
 def segment_bag(table: jax.Array, ids: jax.Array, vocab_block: int = 2048,
-                batch_block: int = 256, interpret: bool = True) -> jax.Array:
+                batch_block: int = 256, *, interpret: bool) -> jax.Array:
     """table: (V, D); ids: (B, L) int32 with -1 padding.  Returns (B, D)
     sum-bags in table.dtype (fp32 accumulation across vocab tiles).
     V % vocab_block == 0 or vocab_block clamped; same for B."""

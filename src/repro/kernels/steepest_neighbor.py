@@ -57,7 +57,7 @@ def _kernel(center, lo, hi, out, *, offsets, block_x, R, fill):
 @functools.partial(jax.jit,
                    static_argnames=("connectivity", "block_x", "interpret"))
 def steepest_neighbor(order: jax.Array, connectivity: int = 6,
-                      block_x: int = 8, interpret: bool = True) -> jax.Array:
+                      block_x: int = 8, *, interpret: bool) -> jax.Array:
     """order: (X, Y, Z) int32 (unique values >= 0).  Returns (X, Y, Z) int32
     global flat ids.  On-domain boundary handled by -fill halo planes."""
     if order.ndim != 3:

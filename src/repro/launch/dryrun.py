@@ -24,6 +24,7 @@ import jax
 from repro.launch.mesh import make_production_mesh
 from repro.launch.cells import build_cell, all_cells
 from repro.runtime.meshctx import use_mesh
+from repro.launch.jax_cache import enable_compile_cache
 
 _DTYPE_BYTES = {
     "f64": 8, "s64": 8, "u64": 8, "c64": 8,
@@ -121,6 +122,7 @@ def run_cell(arch, shape_name, mesh, mesh_label, smoke, out_dir,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

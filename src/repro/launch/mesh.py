@@ -1,15 +1,23 @@
 """Production meshes.  Functions (not module constants) so importing never
-touches jax device state."""
+touches jax device state.  Every mesh has Auto axes: the models place
+arrays with `with_sharding_constraint`, and the DPC programs return
+ordinary sharded arrays, neither of which Explicit axes accept."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes, devices=None):
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(shape),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_flat_mesh(mesh=None, name: str = "shards"):
@@ -17,7 +25,7 @@ def make_flat_mesh(mesh=None, name: str = "shards"):
     if mesh is None:
         mesh = make_production_mesh()
     devices = mesh.devices.reshape(-1)
-    return jax.make_mesh((devices.size,), (name,), devices=devices)
+    return _mesh((devices.size,), (name,), devices=devices)
 
 
 def make_block_mesh(layout, mesh=None):
@@ -45,4 +53,4 @@ def make_smoke_mesh(n: int | None = None):
     """Whatever this host has (tests / examples)."""
     n = n or len(jax.devices())
     shape = (1, n) if n > 1 else (1, 1)
-    return jax.make_mesh(shape, ("data", "model"))
+    return _mesh(shape, ("data", "model"))

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 
 from repro.launch.mesh import make_production_mesh
 from repro.launch.dryrun import run_cell
+from repro.launch.jax_cache import enable_compile_cache
 
 # (cell, variant_tag, hypothesis, cfg_transform)
 VARIANTS = []
@@ -118,6 +119,7 @@ _v("dpc_grid:cc_1024", "no_mask_gather",
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--cell", default=None)
     ap.add_argument("--out", default="experiments/perf")

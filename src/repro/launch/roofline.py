@@ -24,6 +24,7 @@ import dataclasses
 import json
 
 import jax
+from repro.launch.jax_cache import enable_compile_cache
 
 PEAK_FLOPS = 197e12
 HBM_BW = 819e9
@@ -172,6 +173,7 @@ def fmt_table(rows):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

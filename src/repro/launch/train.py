@@ -25,9 +25,11 @@ from repro.runtime.driver import TrainDriver
 from repro.runtime.meshctx import use_mesh
 from repro.data.tokens import TokenStream
 from repro.launch.mesh import make_smoke_mesh
+from repro.launch.jax_cache import enable_compile_cache
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b")
     ap.add_argument("--smoke", action="store_true",

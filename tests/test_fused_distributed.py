@@ -1,7 +1,7 @@
-"""Fused block-local phase inside the distributed hot path: labels stay
-bit-identical to the pure-oracle paths on ragged corpus cases, for the
-single-request AND the batched (vmap-inside-shard_map) entry points, while
-`DPCStats.kernel_rounds` certifies the global doubling rounds saved.
+"""Fused pointer-init kernel inside the distributed hot path: labels and
+the local doubling rounds stay bit-identical to the jnp init on ragged
+corpus cases, for the single-request AND the batched (vmap-inside-shard_map)
+entry points.
 
 Runs in a subprocess with XLA_FLAGS=--xla_force_host_platform_device_count=8
 so the main test process keeps its single-device view.
@@ -49,16 +49,9 @@ _WORKER = textwrap.dedent("""
         l1, s1 = distributed_manifold(order, mesh, conn, fused_impl="kernel")
         if not (np.asarray(l0) == np.asarray(l1)).all():
             failures.append(("manifold", seed))
-        # the kernel certifies the saturation depth; the jnp path reports 0
-        if not (int(s1.kernel_rounds) >= 1 and int(s0.kernel_rounds) == 0):
-            failures.append(("manifold-rounds", seed))
-        # fused local loop never needs MORE rounds than the unfused one
-        if int(s1.local_iters) > int(s0.local_iters):
+        # the same init bits give the same local doubling rounds
+        if int(s1.local_iters) != int(s0.local_iters):
             failures.append(("manifold-iters", seed))
-        d = s1.as_dict()
-        if not (d["global_iters_saved"]
-                == max(d["kernel_rounds"] - d["local_iters"], 0)):
-            failures.append(("manifold-saved", seed))
 
         c0, t0 = distributed_connected_components(mask, mesh, conn,
                                                   fused_impl="ref")
@@ -66,8 +59,8 @@ _WORKER = textwrap.dedent("""
                                                   fused_impl="kernel")
         if not (np.asarray(c0) == np.asarray(c1)).all():
             failures.append(("cc", seed))
-        if not int(t1.kernel_rounds) >= 1:
-            failures.append(("cc-rounds", seed))
+        if int(t1.local_iters) != int(t0.local_iters):
+            failures.append(("cc-iters", seed))
 
     # batched: one ragged 3-D case, per-item bit-identity vs single-request
     seed, shape, layout, conn, mask_p = corpus_3d(1)[0]
@@ -92,8 +85,6 @@ _WORKER = textwrap.dedent("""
                                                  fused_impl="kernel")
         if not (np.asarray(bc[i]) == np.asarray(ci)).all():
             failures.append(("batch-cc", i))
-    if not all(r >= 1 for r in np.asarray(bs.kernel_rounds).tolist()):
-        failures.append(("batch-rounds", -1))
 
     assert not failures, failures
     print("FUSED-DIST-OK")

@@ -92,23 +92,22 @@ def _fused_fixpoint_check(field, conn, mode, ptr):
 @pytest.mark.parametrize("conn", [6, 14, 18, 26])
 @pytest.mark.parametrize("mode", ["manifold", "cc"])
 def test_fused_kernel_vs_ref(conn, mode):
-    """Kernel == bit-exact oracle (pointers AND round count) on a ragged
-    prime extent with a tile size forcing a ragged last slab, plus the
-    distributed self-mask override."""
-    shape = (7, 3, 5)
+    """Kernel == bit-exact oracle on extents that exercise every halo read:
+    a ragged last x tile, y tiles of 8 rows (the 8-row halo groups and, for
+    conn >= 14, the x/y corner groups), a z extent that is not a lane
+    multiple, plus the distributed ghost override on two axes."""
+    shape = (7, 24, 5)
     rng = np.random.default_rng(conn * 7 + (mode == "cc"))
     if mode == "manifold":
         field = jnp.asarray(rng.permutation(int(np.prod(shape)))
                             .reshape(shape).astype(np.int32))
     else:
         field = jnp.asarray(rng.random(shape) < 0.6)
-    smask = jnp.asarray(rng.random(shape) < 0.2)
-    got, rounds = fused_local_phase(field, conn, mode=mode, self_mask=smask,
-                                    block_x=4, interpret=True)
-    want, wrounds = ref.fused_local_phase_ref(field, conn, mode=mode,
-                                              self_mask=smask, block_x=4)
+    got = fused_local_phase(field, conn, mode=mode, ghost_axes=(0, 1),
+                            tile=(3, 8), interpret=True)
+    want = ref.fused_local_phase_ref(field, conn, mode=mode,
+                                     ghost_axes=(0, 1))
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-    assert int(rounds) == int(wrounds) >= 1
 
 
 @pytest.mark.parametrize("seed", GRID_SEED_CORPUS)
@@ -125,46 +124,53 @@ def test_fused_kernel_corpus(seed):
                         .reshape(shape).astype(np.int32))
     mask = jnp.asarray(rng.random(shape) < mask_p)
     for mode, field in (("manifold", order), ("cc", mask)):
-        got, rounds = fused_local_phase(field, conn, mode=mode, block_x=4,
-                                        interpret=True)
-        want, wrounds = ref.fused_local_phase_ref(field, conn, mode=mode,
-                                                  block_x=4)
+        got = fused_local_phase(field, conn, mode=mode, tile=(4, shape[1]),
+                                interpret=True)
+        want = ref.fused_local_phase_ref(field, conn, mode=mode)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-        assert int(rounds) == int(wrounds)
         _fused_fixpoint_check(field, conn, mode, got)
 
 
 @pytest.mark.parametrize("block_x", [1, 3, 8])
 def test_fused_kernel_blocking_invariance(block_x):
-    """Any tile size gives the same compress fixpoint (block_x=3 on x=13
-    forces a ragged last slab; block_x=1 degenerates to pure init + the
-    single-plane saturation)."""
-    shape = (13, 2, 3)
+    """Any tile gives the same pointers (block_x=3 on x=13 forces a ragged
+    last tile; block_x=1 reads both x neighbors from the halo planes; y
+    tiles of 8 and 16 rows on y=16 with the 14-stencil's corner reads)."""
+    shape = (13, 16, 3)
     rng = np.random.default_rng(block_x)
     order = jnp.asarray(rng.permutation(int(np.prod(shape)))
                         .reshape(shape).astype(np.int32))
-    got, _ = fused_local_phase(order, 6, mode="manifold", block_x=block_x,
-                               interpret=True)
-    want, _ = ref.fused_local_phase_ref(order, 6, mode="manifold",
-                                        block_x=block_x)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-    _fused_fixpoint_check(order, 6, "manifold", got)
+    want = ref.fused_local_phase_ref(order, 14, mode="manifold")
+    for by in (8, 16):
+        got = fused_local_phase(order, 14, mode="manifold",
+                                tile=(block_x, by), interpret=True)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    _fused_fixpoint_check(order, 14, "manifold", got)
 
 
 def test_fused_dispatch_fallback_and_validation():
-    """ops.fused_local_phase: jnp fallback for 2-D fields and unsupported
-    connectivities (kernel_rounds == 0), ValueError on a bad impl."""
+    """ops.fused_local_phase: jnp init for 2-D fields and unsupported
+    connectivities, the same ghost override on the jnp and kernel paths,
+    ValueError on a bad impl or mode."""
     from repro.kernels import ops
     rng = np.random.default_rng(5)
     order2d = jnp.asarray(rng.permutation(30).reshape(5, 6).astype(np.int32))
-    d, r = ops.fused_local_phase(order2d, connectivity=4, mode="manifold",
-                                 impl="kernel")
-    assert d.shape == (5, 6) and int(r) == 0
+    d = ops.fused_local_phase(order2d, connectivity=4, mode="manifold",
+                              impl="kernel")
+    want2d = grid_steepest(order2d, 4).reshape(order2d.shape)
+    np.testing.assert_array_equal(np.asarray(d), np.asarray(want2d))
     order3d = jnp.asarray(rng.permutation(60).reshape(5, 4, 3)
                           .astype(np.int32))
-    got = ops.fused_local_phase(order3d, 6, mode="manifold", impl="ref")[0]
+    got = ops.fused_local_phase(order3d, 6, mode="manifold", impl="ref")
     want = grid_steepest(order3d, 6).reshape(order3d.shape)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    mask3d = jnp.asarray(rng.random((5, 4, 3)) < 0.5)
+    for mode, field in (("manifold", order3d), ("cc", mask3d)):
+        a = ops.fused_local_phase(field, 6, mode, ghost_axes=(0, 2),
+                                  impl="ref")
+        b = ops.fused_local_phase(field, 6, mode, ghost_axes=(0, 2),
+                                  impl="kernel")
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     with pytest.raises(ValueError, match="impl"):
         ops.fused_local_phase(order3d, 6, impl="nope")
     with pytest.raises(ValueError, match="mode"):
@@ -175,11 +181,13 @@ def test_fused_kernel_rejects_2d_and_bad_conn():
     rng = np.random.default_rng(6)
     order2d = jnp.asarray(rng.permutation(30).reshape(5, 6).astype(np.int32))
     with pytest.raises(ValueError, match="3-D"):
-        fused_local_phase(order2d, 4)
+        fused_local_phase(order2d, 4, interpret=True)
     order3d = jnp.asarray(rng.permutation(60).reshape(5, 4, 3)
                           .astype(np.int32))
     with pytest.raises(ValueError, match="connectivit"):
-        fused_local_phase(order3d, 5)
+        fused_local_phase(order3d, 5, interpret=True)
+    with pytest.raises(ValueError, match="tile"):
+        fused_local_phase(order3d, 6, tile=(2, 2), interpret=True)
 
 
 def test_steepest_kernel_rejects_2d_and_bad_conn():
@@ -199,7 +207,7 @@ def test_fused_kernel_rejects_int64_without_x64():
     assert not jax.config.jax_enable_x64  # test-process invariant
     order = jnp.asarray(np.arange(24, dtype=np.int32).reshape(4, 3, 2))
     with pytest.raises(ValueError, match="x64"):
-        fused_local_phase(order, 6, id_dtype=jnp.int64)
+        fused_local_phase(order, 6, id_dtype=jnp.int64, interpret=True)
 
 
 _FUSED_X64_WORKER = textwrap.dedent("""
@@ -216,13 +224,12 @@ _FUSED_X64_WORKER = textwrap.dedent("""
     shape = (7, 3, 4)
     order = jnp.asarray(rng.permutation(int(np.prod(shape)))
                         .reshape(shape).astype(np.int32))
-    got, r = fused_local_phase(order, 14, mode="manifold", block_x=4,
-                               interpret=True, id_dtype=jnp.int64)
+    got = fused_local_phase(order, 14, mode="manifold", ghost_axes=(1,),
+                            tile=(4, 3), interpret=True, id_dtype=jnp.int64)
     assert got.dtype == jnp.int64
-    want, wr = fused_local_phase_ref(order, 14, mode="manifold", block_x=4,
-                                     id_dtype=jnp.int64)
+    want = fused_local_phase_ref(order, 14, mode="manifold", ghost_axes=(1,),
+                                 id_dtype=jnp.int64)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-    assert int(r) == int(wr)
     print("FUSED-X64-OK")
 """)
 

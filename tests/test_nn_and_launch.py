@@ -105,6 +105,22 @@ def test_perlin_shard_consistency():
     np.testing.assert_array_equal(full[16:24], slab)
 
 
+@pytest.mark.parametrize("shape, seed, origin", [
+    ((40, 16, 8), 0, None),        # two device slabs, the last ragged
+    ((20, 17, 9), 3, None),
+    ((12, 10, 8), 1, (5, 0, 0)),
+    ((64, 64), 1, None),
+])
+def test_perlin_device_matches_host(shape, seed, origin):
+    """The device generator evaluates the same formula: equal to the numpy
+    field up to float32 rounding of its per-vertex sum."""
+    from repro.data.perlin import perlin_noise_device
+    want = perlin_noise(shape, frequency=0.1, seed=seed, origin=origin)
+    got = np.asarray(perlin_noise_device(shape, 0.1, seed, origin))
+    assert got.shape == want.shape and got.dtype == np.float32
+    np.testing.assert_allclose(got, want, rtol=0, atol=1e-6)
+
+
 def test_perlin_statistics():
     f = perlin_noise((64, 64), frequency=0.1, seed=1)
     assert abs(float(f.mean())) < 0.1
