@@ -48,6 +48,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .stats import host_read
+
 
 TABLE_MODES = ("replicated", "sharded")
 
@@ -94,8 +96,9 @@ def pointer_chase(T, lookup, max_iter: int = 64):
         nt = lookup(t)
         return nt, jnp.any(nt != t), i + jnp.int32(1)
 
-    T, ch, iters = lax.while_loop(cond, body,
-                                  (T, jnp.asarray(True), jnp.int32(0)))
+    with jax.named_scope("dpc.table.chase"):
+        T, ch, iters = lax.while_loop(cond, body,
+                                      (T, jnp.asarray(True), jnp.int32(0)))
     return T, iters, ~ch
 
 
@@ -115,16 +118,18 @@ def make_group_max(Tstar):
     value-search substitution.
     """
     msize = Tstar.size
-    # (value, slot) keys are unique, so an unstable two-key sort returns the
-    # stable argsort — and compiles far faster for a TPU than a stable sort
-    sorted_vals, perm = lax.sort(
-        (Tstar, jnp.arange(msize, dtype=jnp.int32)), num_keys=2,
-        is_stable=False)
-    run_start = jnp.concatenate(
-        [jnp.ones((1,), bool), sorted_vals[1:] != sorted_vals[:-1]])
-    run_id = jnp.cumsum(run_start) - 1
-    inv_perm = jnp.zeros(msize, dtype=jnp.int32).at[perm].set(
-        jnp.arange(msize, dtype=jnp.int32))
+    with jax.named_scope("dpc.table.propagate"):
+        # (value, slot) keys are unique, so an unstable two-key sort returns
+        # the stable argsort — and compiles far faster for a TPU than a
+        # stable sort
+        sorted_vals, perm = lax.sort(
+            (Tstar, jnp.arange(msize, dtype=jnp.int32)), num_keys=2,
+            is_stable=False)
+        run_start = jnp.concatenate(
+            [jnp.ones((1,), bool), sorted_vals[1:] != sorted_vals[:-1]])
+        run_id = jnp.cumsum(run_start) - 1
+        inv_perm = jnp.zeros(msize, dtype=jnp.int32).at[perm].set(
+            jnp.arange(msize, dtype=jnp.int32))
 
     def group_max(L):
         gm = jax.ops.segment_max(L[perm], run_id, num_segments=msize)
@@ -154,8 +159,9 @@ def hook_propagate(Tstar, cut_max, group_max, max_iter: int = 64):
         nxt = group_max(cut_max(L))
         return nxt, jnp.any(nxt != L), i + jnp.int32(1)
 
-    L, ch, iters = lax.while_loop(
-        cond, body, (Tstar, jnp.asarray(True), jnp.int32(0)))
+    with jax.named_scope("dpc.table.propagate"):
+        L, ch, iters = lax.while_loop(
+            cond, body, (Tstar, jnp.asarray(True), jnp.int32(0)))
     return L, iters, ~ch
 
 
@@ -176,13 +182,14 @@ def value_substitute(o, chased, sorted_vals, g_sorted):
     vertices of the same local piece.  `o` is the pre-chase label; `< 0`
     (unmasked) entries stay -1.
     """
-    chased = materialize(chased)
-    idx = materialize(jnp.clip(jnp.searchsorted(sorted_vals, chased),
-                               0, sorted_vals.shape[0] - 1))
-    found = sorted_vals[idx] == chased
-    improved = jnp.where(found & (chased >= 0),
-                         jnp.maximum(g_sorted[idx], chased), chased)
-    return jnp.where(o < 0, -1, improved)
+    with jax.named_scope("dpc.table.substitute"):
+        chased = materialize(chased)
+        idx = materialize(jnp.clip(jnp.searchsorted(sorted_vals, chased),
+                                   0, sorted_vals.shape[0] - 1))
+        found = sorted_vals[idx] == chased
+        improved = jnp.where(found & (chased >= 0),
+                             jnp.maximum(g_sorted[idx], chased), chased)
+        return jnp.where(o < 0, -1, improved)
 
 
 def sharded_fixpoint(own0, exchange, refine, reduce_any, max_rounds: int = 64):
@@ -237,7 +244,7 @@ def check_converged(flag, what: str, max_iter: int) -> None:
     `converged` stats field themselves.
     """
     try:
-        ok = bool(np.all(np.asarray(flag)))
+        ok = bool(np.all(host_read(flag)))
     except jax.errors.TracerArrayConversionError:
         return
     if not ok:

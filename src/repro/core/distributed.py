@@ -336,14 +336,15 @@ def _gather_table(owned, dec: BlockDecomp):
     """The single communication phase: all_gather every block's owned lo/hi
     face along each decomposed axis into one replicated flat table laid out
     as BlockDecomp.boundary_pos expects."""
-    parts = []
-    for a in range(dec.k):
-        lo = lax.index_in_dim(owned, 0, a, keepdims=False)
-        hi = lax.index_in_dim(owned, dec.local[a] - 1, a, keepdims=False)
-        bt = jnp.stack([lo.reshape(-1), hi.reshape(-1)])     # (2, F_a)
-        g = lax.all_gather(bt, dec.names)                    # (nblocks, 2, F_a)
-        parts.append(g.reshape(-1))
-    return jnp.concatenate(parts)
+    with jax.named_scope("dpc.table.gather"):
+        parts = []
+        for a in range(dec.k):
+            lo = lax.index_in_dim(owned, 0, a, keepdims=False)
+            hi = lax.index_in_dim(owned, dec.local[a] - 1, a, keepdims=False)
+            bt = jnp.stack([lo.reshape(-1), hi.reshape(-1)])   # (2, F_a)
+            g = lax.all_gather(bt, dec.names)              # (nblocks, 2, F_a)
+            parts.append(g.reshape(-1))
+        return jnp.concatenate(parts)
 
 
 def _own_faces(owned, dec: BlockDecomp):
@@ -351,12 +352,14 @@ def _own_faces(owned, dec: BlockDecomp):
     face along each decomposed axis, flattened exactly like one block's
     segment of the gathered table (`row = local_face_offset[a] + j*F_a + r`).
     `_gather_table` == all_gather of every block's `_own_faces`."""
-    parts = []
-    for a in range(dec.k):
-        lo = lax.index_in_dim(owned, 0, a, keepdims=False)
-        hi = lax.index_in_dim(owned, dec.local[a] - 1, a, keepdims=False)
-        parts.append(jnp.stack([lo.reshape(-1), hi.reshape(-1)]).reshape(-1))
-    return jnp.concatenate(parts)
+    with jax.named_scope("dpc.table.gather"):
+        parts = []
+        for a in range(dec.k):
+            lo = lax.index_in_dim(owned, 0, a, keepdims=False)
+            hi = lax.index_in_dim(owned, dec.local[a] - 1, a, keepdims=False)
+            parts.append(
+                jnp.stack([lo.reshape(-1), hi.reshape(-1)]).reshape(-1))
+        return jnp.concatenate(parts)
 
 
 def _table_compress(T, dec: BlockDecomp, max_iter=64):
@@ -444,7 +447,11 @@ class _ShardGeom:
                 for a in self.act:
                     chunks.extend(axis_parts(own, a))
                 return jnp.concatenate(chunks) if len(chunks) > 1 else own
-        return exchange
+
+        def scoped(own):
+            with jax.named_scope("dpc.table.gather"):
+                return exchange(own)
+        return scoped
 
     def pos_to_stack(self, s):
         """Global table slot -> (in_stack, flat stack index).  Callers gate
@@ -591,11 +598,12 @@ def _sharded_manifold_resolve(owned, dec: BlockDecomp, connectivity,
     stackT, _, rounds, iters, ok = sharded_fixpoint(
         T0, exchange, refine, reduce_any, max_rounds=max_iter)
 
-    o = owned.ravel()
-    is_b, s = dec.boundary_pos(jnp.clip(o, 0), jnp)
-    is_b, okp, idx = materialize((is_b, *geom.pos_to_stack(s)))
-    final = jnp.where((o >= 0) & is_b & okp,
-                      stackT[jnp.clip(idx, 0, geom.stack_size - 1)], o)
+    with jax.named_scope("dpc.table.substitute"):
+        o = owned.ravel()
+        is_b, s = dec.boundary_pos(jnp.clip(o, 0), jnp)
+        is_b, okp, idx = materialize((is_b, *geom.pos_to_stack(s)))
+        final = jnp.where((o >= 0) & is_b & okp,
+                          stackT[jnp.clip(idx, 0, geom.stack_size - 1)], o)
     return final, geom, rounds, iters, ok
 
 
@@ -610,14 +618,16 @@ def _manifold_block(order_blk, *, dec: BlockDecomp, connectivity,
 
     # 1. order halo (fill -1: below every real order value, never steepest)
     ext = order_blk
-    for a in range(dec.k):
-        ext = _halo_extend(ext, a, dec.names[a], dec.layout[a], -1)
+    with jax.named_scope("dpc.halo"):
+        for a in range(dec.k):
+            ext = _halo_extend(ext, a, dec.names[a], dec.layout[a], -1)
 
     # 2. steepest init in local ids, the ghost layers (first/last layer of
     #    every decomposed axis) pretending to be maxima (Alg. 1 lines 6-8)
-    d = fused_local_phase(ext, connectivity, mode="manifold",
-                          ghost_axes=tuple(range(dec.k)),
-                          impl=fused_impl).ravel()
+    with jax.named_scope("dpc.init"):
+        d = fused_local_phase(ext, connectivity, mode="manifold",
+                              ghost_axes=tuple(range(dec.k)),
+                              impl=fused_impl).ravel()
 
     # 3. local compression to the block fixpoint (Alg. 1 lines 9-19)
     d, local_iters = path_compress(d)
@@ -625,9 +635,10 @@ def _manifold_block(order_blk, *, dec: BlockDecomp, connectivity,
     # 4. to global ids + the single communication phase (Alg. 2); pad cells
     #    of a ragged block carry the sentinel -1, which the chase fixes and
     #    the substitution skips (deviation (p) in DESIGN.md)
-    owned = _to_global(d.reshape(dec.ext)[dec.owned_slices], dec)
-    if dec.ragged:
-        owned = jnp.where(_owned_valid(dec), owned, dec.id_dtype(-1))
+    with jax.named_scope("dpc.ids"):
+        owned = _to_global(d.reshape(dec.ext)[dec.owned_slices], dec)
+        if dec.ragged:
+            owned = jnp.where(_owned_valid(dec), owned, dec.id_dtype(-1))
     isz = np.dtype(dec.id_dtype).itemsize
 
     if table_mode == "replicated":
@@ -637,10 +648,11 @@ def _manifold_block(order_blk, *, dec: BlockDecomp, connectivity,
         T, table_iters, chase_ok = _table_compress(T, dec, table_max_iter)
 
         # 6. final substitution (Alg. 2 lines 27-33)
-        o = owned.ravel()
-        is_b, pos = materialize(dec.boundary_pos(jnp.clip(o, 0), jnp))
-        final = jnp.where((o >= 0) & is_b,
-                          T[jnp.clip(pos, 0, T.size - 1)], o)
+        with jax.named_scope("dpc.table.substitute"):
+            o = owned.ravel()
+            is_b, pos = materialize(dec.boundary_pos(jnp.clip(o, 0), jnp))
+            final = jnp.where((o >= 0) & is_b,
+                              T[jnp.clip(pos, 0, T.size - 1)], o)
         comm = jnp.int32(1)
         exch_rounds = jnp.int32(0)
         ghost_bytes = jnp.float32(dec.n_valid_slots * isz)
@@ -648,8 +660,9 @@ def _manifold_block(order_blk, *, dec: BlockDecomp, connectivity,
         converged = chase_ok.astype(jnp.int32)
     else:
         # 4-6. sharded: own faces + one-hop halo, neighbor-relay fixpoint
-        final, geom, exch_rounds, iters, ok = _sharded_manifold_resolve(
-            owned, dec, connectivity, table_max_iter)
+        with jax.named_scope("dpc.table"):
+            final, geom, exch_rounds, iters, ok = _sharded_manifold_resolve(
+                owned, dec, connectivity, table_max_iter)
         table_iters, _, converged = _preduce_stats(dec, iters, exch_rounds,
                                                    ok)
         comm = exch_rounds                 # one exchange phase per round
@@ -673,6 +686,15 @@ def _manifold_block(order_blk, *, dec: BlockDecomp, connectivity,
     return final.reshape(order_blk.shape), stats
 
 
+@partial(jax.jit, static_argnums=1)
+def _flip_order(order, n: int):
+    """The ascending manifold is the descending one of the flipped order
+    field n - 1 - order (n vertices); jitted, so its op carries its scope
+    into the device trace."""
+    with jax.named_scope("dpc.order.flip"):
+        return n - 1 - order
+
+
 def distributed_manifold(order, mesh: Mesh, connectivity: int = 6,
                          descending: bool = True, fused_impl: str = "auto",
                          table_mode: str = "replicated",
@@ -693,8 +715,9 @@ def distributed_manifold(order, mesh: Mesh, connectivity: int = 6,
                          connectivity, True, fused_impl, table_mode,
                          table_max_iter)
     if not descending:
-        order = order.size - 1 - order  # ascending = descending on flipped
-    labels, stats = prog(order)
+        order = _flip_order(order, order.size)
+    with jax.profiler.TraceAnnotation("dpc.dispatch"):
+        labels, stats = prog(order)
     check_converged(stats.converged, "distributed_manifold", table_max_iter)
     return labels, stats
 
@@ -731,8 +754,9 @@ def _cc_local_fixpoint(d, mask_ext, connectivity, max_rounds=64):
         nxt, it = path_compress(st)
         return nxt, jnp.any(nxt != cur), r + jnp.int32(1), its + it
 
-    d, _, rounds, its = lax.while_loop(
-        cond, body, (d, jnp.asarray(True), jnp.int32(0), its0))
+    with jax.named_scope("dpc.cc_stitch"):
+        d, _, rounds, its = lax.while_loop(
+            cond, body, (d, jnp.asarray(True), jnp.int32(0), its0))
     return d, rounds, its
 
 
@@ -831,11 +855,12 @@ def _sharded_cc_resolve(owned, mask_owned, coords, dec: BlockDecomp,
     # has one, then the value search over the STATIC stack labels (an owned
     # interior root is not a slot but shares its value with its piece's cut
     # vertices, which are in the own chunk whenever the piece reaches a cut)
-    o = owned.ravel()
-    is_b, s = dec.boundary_pos(jnp.clip(o, 0), jnp)
-    is_b, okp, idx = materialize((is_b, *geom.pos_to_stack(s)))
-    chased = jnp.where((o >= 0) & is_b & okp,
-                       stackG[jnp.clip(idx, 0, geom.stack_size - 1)], o)
+    with jax.named_scope("dpc.table.substitute"):
+        o = owned.ravel()
+        is_b, s = dec.boundary_pos(jnp.clip(o, 0), jnp)
+        is_b, okp, idx = materialize((is_b, *geom.pos_to_stack(s)))
+        chased = jnp.where((o >= 0) & is_b & okp,
+                           stackG[jnp.clip(idx, 0, geom.stack_size - 1)], o)
     final = value_substitute(o, chased, sorted_vals, stackG[perm])
     return final, Ms, geom, rounds, iters, ok
 
@@ -859,22 +884,25 @@ def _cc_block(mask_blk, coords=None, *, dec: BlockDecomp, connectivity,
 
     # 1. mask halo (fill False: domain boundary is never masked)
     ext = mask_blk
-    for a in range(dec.k):
-        ext = _halo_extend(ext, a, dec.names[a], dec.layout[a], False)
+    with jax.named_scope("dpc.halo"):
+        for a in range(dec.k):
+            ext = _halo_extend(ext, a, dec.names[a], dec.layout[a], False)
 
     # 2. init: largest masked neighbor id, masked ghosts pretending self
-    d = fused_local_phase(ext, connectivity, mode="cc",
-                          ghost_axes=tuple(range(dec.k)),
-                          impl=fused_impl).ravel()
+    with jax.named_scope("dpc.init"):
+        d = fused_local_phase(ext, connectivity, mode="cc",
+                              ghost_axes=tuple(range(dec.k)),
+                              impl=fused_impl).ravel()
 
     # 3. local CC fixpoint (stitch + compress, Alg. 3)
     d, stitch_rounds, local_iters = _cc_local_fixpoint(
         d, ext, connectivity)
 
     # 4. to global ids
-    do = d.reshape(dec.ext)[dec.owned_slices]
-    owned = jnp.where(do >= 0, _to_global(jnp.clip(do, 0), dec),
-                      dec.id_dtype(-1))
+    with jax.named_scope("dpc.ids"):
+        do = d.reshape(dec.ext)[dec.owned_slices]
+        owned = jnp.where(do >= 0, _to_global(jnp.clip(do, 0), dec),
+                          dec.id_dtype(-1))
     isz = np.dtype(dec.id_dtype).itemsize
 
     if table_mode == "replicated":
@@ -896,10 +924,11 @@ def _cc_block(mask_blk, coords=None, *, dec: BlockDecomp, connectivity,
 
         # 6. substitution: chase own label through the table, then take its
         #    group's propagated maximum (value search over the sorted table)
-        o = owned.ravel()
-        is_b, pos = materialize(dec.boundary_pos(jnp.clip(o, 0), jnp))
-        chased = jnp.where((o >= 0) & is_b,
-                           Tstar[jnp.clip(pos, 0, Tstar.size - 1)], o)
+        with jax.named_scope("dpc.table.substitute"):
+            o = owned.ravel()
+            is_b, pos = materialize(dec.boundary_pos(jnp.clip(o, 0), jnp))
+            chased = jnp.where((o >= 0) & is_b,
+                               Tstar[jnp.clip(pos, 0, Tstar.size - 1)], o)
         final = value_substitute(o, chased, sorted_vals, G[perm])
 
         table_iters = table_iters + prop_iters
@@ -914,9 +943,10 @@ def _cc_block(mask_blk, coords=None, *, dec: BlockDecomp, connectivity,
                        / jnp.float32(max(dec.n_valid_slots, 1)))
     else:
         # 4b-6. sharded: max-flooding on the own+halo stack (no gather)
-        final, Ms, geom, exch_rounds, iters, ok = _sharded_cc_resolve(
-            owned, ext[dec.owned_slices], coords, dec, connectivity,
-            gather_mask, table_max_iter)
+        with jax.named_scope("dpc.table"):
+            final, Ms, geom, exch_rounds, iters, ok = _sharded_cc_resolve(
+                owned, ext[dec.owned_slices], coords, dec, connectivity,
+                gather_mask, table_max_iter)
         table_iters, _, converged = _preduce_stats(dec, iters, exch_rounds,
                                                    ok)
         comm = exch_rounds + jnp.int32(1)  # +1: the static label/mask stack
@@ -967,8 +997,9 @@ def distributed_connected_components(mask, mesh: Mesh, connectivity: int = 6,
     _check_table_mode(table_mode)
     prog = _grid_program("cc", mesh, tuple(mask.shape), False, connectivity,
                          gather_mask, fused_impl, table_mode, table_max_iter)
-    labels, stats = prog(mask, _decomp_for(mesh, mask.shape)
-                         .boundary_coords_dev)
+    coords = _decomp_for(mesh, mask.shape).boundary_coords_dev
+    with jax.profiler.TraceAnnotation("dpc.dispatch"):
+        labels, stats = prog(mask, coords)
     check_converged(stats.converged, "distributed_connected_components",
                     table_max_iter)
     return labels, stats
@@ -1021,7 +1052,8 @@ def _grid_program(kind, mesh: Mesh, grid, batched, connectivity,
         if dec.ragged:
             pads = [(0, 0)] * lead_axes + [(0, dec.padded[i] - dec.grid[i])
                                            for i in range(dec.ndim)]
-            x = jnp.pad(x, pads, constant_values=fill)
+            with jax.named_scope("dpc.ids"):
+                x = jnp.pad(x, pads, constant_values=fill)
         if not batched:
             labels, stats = one(x, *args)
         elif x.shape[0] == 1:
@@ -1034,8 +1066,9 @@ def _grid_program(kind, mesh: Mesh, grid, batched, connectivity,
         else:
             labels, stats = many(x, *args)
         if dec.ragged:
-            labels = labels[(slice(None),) * lead_axes
-                            + tuple(slice(0, g) for g in dec.grid)]
+            with jax.named_scope("dpc.ids"):
+                labels = labels[(slice(None),) * lead_axes
+                                + tuple(slice(0, g) for g in dec.grid)]
         return labels, stats
 
     return jax.jit(run)
@@ -1054,8 +1087,9 @@ def distributed_manifold_batch(orders, mesh: Mesh, connectivity: int = 6,
     prog = _grid_program("manifold", mesh, grid, True, connectivity, True,
                          fused_impl, table_mode, table_max_iter)
     if not descending:
-        orders = math.prod(grid) - 1 - orders  # ascending: flipped order
-    labels, stats = prog(orders)
+        orders = _flip_order(orders, math.prod(grid))
+    with jax.profiler.TraceAnnotation("dpc.dispatch"):
+        labels, stats = prog(orders)
     check_converged(stats.converged, "distributed_manifold_batch",
                     table_max_iter)
     return labels, stats
@@ -1075,7 +1109,9 @@ def distributed_connected_components_batch(masks, mesh: Mesh,
     grid = tuple(masks.shape[1:])
     prog = _grid_program("cc", mesh, grid, True, connectivity, gather_mask,
                          fused_impl, table_mode, table_max_iter)
-    labels, stats = prog(masks, _decomp_for(mesh, grid).boundary_coords_dev)
+    coords = _decomp_for(mesh, grid).boundary_coords_dev
+    with jax.profiler.TraceAnnotation("dpc.dispatch"):
+        labels, stats = prog(masks, coords)
     check_converged(stats.converged, "distributed_connected_components_batch",
                     table_max_iter)
     return labels, stats

@@ -39,19 +39,25 @@ def compute_order(scalars: jax.Array, ids: jax.Array | None = None) -> jax.Array
     the stable lexsort's permutation bit for bit, and it compiles for a TPU
     several times faster than a stable float sort (DESIGN.md §Perf).
     float64 / 64-bit integer scalars (x64 only) keep the float lexsort.
+    Under a caller's jit its ops carry the `dpc.order` scope; called
+    eagerly they are dispatched op by op and carry none.  (One jitted
+    program lowered the MS query's peak device memory on the chip: a change
+    of its own, PERF.md §7.)
     """
-    flat = scalars.ravel()
-    n = flat.shape[0]
-    pos = jnp.arange(n, dtype=jnp.int32)
-    if flat.dtype.itemsize > 4:
-        perm = jnp.lexsort((pos if ids is None else ids.ravel(), flat))
-    else:
-        keys = (_sort_key(flat),) + (() if ids is None else (ids.ravel(),))
-        perm = lax.sort(keys + (pos,), num_keys=len(keys) + 1,
-                        is_stable=False)[-1]
-    order = jnp.zeros(n, dtype=jnp.int32).at[perm].set(
-        pos, unique_indices=True)
-    return order.reshape(scalars.shape)
+    with jax.named_scope("dpc.order"):
+        flat = scalars.ravel()
+        n = flat.shape[0]
+        pos = jnp.arange(n, dtype=jnp.int32)
+        if flat.dtype.itemsize > 4:
+            perm = jnp.lexsort((pos if ids is None else ids.ravel(), flat))
+        else:
+            keys = (_sort_key(flat),) + (() if ids is None
+                                         else (ids.ravel(),))
+            perm = lax.sort(keys + (pos,), num_keys=len(keys) + 1,
+                            is_stable=False)[-1]
+        order = jnp.zeros(n, dtype=jnp.int32).at[perm].set(
+            pos, unique_indices=True)
+        return order.reshape(scalars.shape)
 
 
 def inverse_permutation(perm: jax.Array) -> jax.Array:

@@ -7,6 +7,7 @@ preview" of the MS complex of Maack et al. [33]).
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import NamedTuple
 
 import jax
@@ -47,11 +48,13 @@ def ascending_manifold(order: jax.Array, connectivity: int = 6,
                                      fused_impl))
 
 
+@partial(jax.jit, static_argnums=2)
 def _pair_hash(desc, asc, n):
     """Injective (desc, asc) -> segment id when n*n fits the id dtype; for
     larger grids consume the (ascending, descending) pair directly."""
     dt = jnp.int64 if jax.config.jax_enable_x64 else jnp.int32
-    return desc.astype(dt) * n + asc.astype(dt)
+    with jax.named_scope("dpc.segmentation"):
+        return desc.astype(dt) * n + asc.astype(dt)
 
 
 def ms_segmentation(order: jax.Array, connectivity: int = 6,

@@ -39,9 +39,10 @@ def path_compress(d: jax.Array, max_iter: int = 64):
         nxt = jump(cur)
         return nxt, jnp.any(nxt != cur), i + jnp.int32(1)
 
-    out, _, iters = lax.while_loop(
-        cond, body, (d, jnp.asarray(True), jnp.int32(0))
-    )
+    with jax.named_scope("dpc.doubling"):
+        out, _, iters = lax.while_loop(
+            cond, body, (d, jnp.asarray(True), jnp.int32(0))
+        )
     return out, iters
 
 

@@ -29,12 +29,19 @@ STAT_FIELDS = ("local_iters", "table_iters", "stitch_rounds", "ghost_bytes",
                "exchange_rounds", "converged")
 
 
+def host_read(x) -> np.ndarray:
+    """`np.asarray` of a device value: a blocking device-to-host read,
+    recorded as one `dpc.host_read` span in the profiler trace."""
+    with jax.profiler.TraceAnnotation("dpc.host_read"):
+        return np.asarray(x)
+
+
 def stats_as_dict(stats) -> dict:
     """Host-side uniform view of any *DPCStats NamedTuple: python scalars
     (0-d) or lists (batched stats with a leading request dim)."""
     out = {}
     for name, val in zip(stats._fields, stats):
-        a = np.asarray(val)
+        a = host_read(val)
         out[name] = a.item() if a.ndim == 0 else a.tolist()
     return out
 

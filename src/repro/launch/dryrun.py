@@ -10,8 +10,7 @@ cell on the production meshes and record memory/cost/collective analysis.
       --shape train_4k --multi-pod
   PYTHONPATH=src python -m repro.launch.dryrun --smoke          # tiny configs
 
-Results land in experiments/dryrun/<mesh>/<arch>__<shape>.json; the roofline
-report (launch/roofline.py) and EXPERIMENTS.md are generated from them.
+Results land in experiments/dryrun/<mesh>/<arch>__<shape>.json.
 """
 import argparse
 import json

@@ -275,8 +275,9 @@ _ROUTES = {"cc": _submit_cc, "ms": _submit_ms, "manifold": _submit_manifold,
 
 def submit(request: TopologyRequest) -> TopologyResult:
     """Route one request to its legacy implementation (bit-identical)."""
-    request.validate()
-    return _ROUTES[request.query](request)
+    with jax.profiler.TraceAnnotation("topology.submit"):
+        request.validate()
+        return _ROUTES[request.query](request)
 
 
 def submit_many(requests) -> list:
