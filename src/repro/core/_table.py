@@ -173,23 +173,77 @@ def materialize(x):
     return lax.optimization_barrier(x)
 
 
+# Owned labels per sort-merge join: the value search joins the table against
+# this many labels at a time (64 joins at 512^3), so a join's temporaries stay
+# a few tens of MB and its sorts compile to little machine code, which a
+# TPU's peak memory counts.
+_JOIN_CHUNK = 1 << 21
+
+
+def _merge_join(q, t_keys, t_delta):
+    """Join labels `q` against the sorted table keys `t_keys`: each label
+    whose value has a slot takes the result of that value's leftmost slot,
+    every other label stays.  `t_delta` is the per-slot result less the next
+    slot's (wrapping), so a suffix sum over the merged order reads the result
+    of the first slot at or after each label."""
+    n, m = q.shape[0], t_keys.shape[0]
+    code = lax.iota(jnp.int32, n + m)
+    # (key, code) pairs are unique and put a value's labels before its
+    # slots, leftmost slot first, so an unstable int32 sort is exact (a
+    # stable one compiles far slower for a TPU)
+    key_s, code_s, delta_s = lax.sort(
+        (jnp.concatenate([q, t_keys]), code,
+         jnp.concatenate([jnp.zeros_like(q), t_delta])),
+        num_keys=2, is_stable=False)
+    # keys ascend, so the running min from the end is the next slot's key
+    top = jnp.iinfo(key_s.dtype).max
+    next_slot_key = lax.cummin(jnp.where(code_s >= n, key_s, top),
+                               reverse=True)
+    # a label at the dtype's top value is its own result either way
+    found = (next_slot_key == key_s) & (key_s != top)
+    joined = jnp.where(found, lax.cumsum(delta_s, reverse=True), key_s)
+    _, out = lax.sort((code_s, joined), num_keys=1, is_stable=False)
+    return out[:n]
+
+
 def value_substitute(o, chased, sorted_vals, g_sorted):
     """Final substitution for CC (Alg. 2 lines 27-33 generalised): take each
     owned label `chased` through the table, then adopt its equal-label
-    group's propagated maximum, found by *value* (searchsorted over the
-    sorted table) — by value because an owned label can name an interior
-    root that is not itself a table slot but shares its value with cut
-    vertices of the same local piece.  `o` is the pre-chase label; `< 0`
-    (unmasked) entries stay -1.
+    group's propagated maximum, found by *value* — by value because an owned
+    label can name an interior root that is not itself a table slot but
+    shares its value with cut vertices of the same local piece.  A label
+    whose value has slots takes the leftmost slot's `g_sorted` (as a
+    left-sided search of `sorted_vals` would).  `o` is the pre-chase label;
+    `< 0` (unmasked) entries stay -1.
+
+    The search is a sort-merge join of the labels against the sorted table
+    (DESIGN.md §Perf), over static chunks of `_JOIN_CHUNK` labels: a
+    binary search would read the table once per halving round, each round a
+    block-sized gather.
     """
     with jax.named_scope("dpc.table.substitute"):
-        chased = materialize(chased)
-        idx = materialize(jnp.clip(jnp.searchsorted(sorted_vals, chased),
-                                   0, sorted_vals.shape[0] - 1))
-        found = sorted_vals[idx] == chased
-        improved = jnp.where(found & (chased >= 0),
-                             jnp.maximum(g_sorted[idx], chased), chased)
-        return jnp.where(o < 0, -1, improved)
+        m, n = sorted_vals.shape[0], chased.shape[0]
+        # a negative label is left as it is, so o < 0 -> -1 can come first
+        q = jnp.where(o < 0, jnp.asarray(-1, chased.dtype), chased)
+        res = jnp.where(sorted_vals >= 0,
+                        jnp.maximum(g_sorted, sorted_vals), sorted_vals)
+        t_delta = res - jnp.concatenate([res[1:], jnp.zeros_like(res[:1])])
+
+        chunk = max(_JOIN_CHUNK, m)
+        if n <= chunk:
+            return _merge_join(q, sorted_vals, t_delta)
+        k = -(-n // chunk)
+        size = -(-n // k)
+
+        def body(i, out):
+            # the last chunk is clamped back to end at n: its overlap with
+            # the one before recomputes the same labels from `q`
+            start = jnp.minimum(i * size, n - size)
+            part = _merge_join(lax.dynamic_slice_in_dim(q, start, size),
+                               sorted_vals, t_delta)
+            return lax.dynamic_update_slice_in_dim(out, part, start, 0)
+
+        return lax.fori_loop(0, k, body, jnp.zeros_like(q))
 
 
 def sharded_fixpoint(own0, exchange, refine, reduce_any, max_rounds: int = 64):

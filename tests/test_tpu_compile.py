@@ -106,3 +106,51 @@ def test_distributed_blocks_compile_on_2x2(topo, monkeypatch):
     ma = compiled.memory_analysis()
     per_device_block = 4 * int(np.prod(dec.local))
     assert ma.argument_size_in_bytes == per_device_block
+
+
+
+def test_one_chip_cc_program_compiles_at_512(topo, monkeypatch):
+    """The one-chip cc program at 512^3 (the benchmark's cc query, the value
+    search a sort-merge join in 64 chunks) compiles for a v5e with the
+    kernel inside.  The chip's peak memory counts the program's machine
+    code, so the join's code must stay within the 1% bound of the cell's
+    peak; its temporaries are set by the stitch loop, not the join."""
+    from repro.core import make_dpc_mesh
+    from repro.core.distributed import _decomp_for, _grid_program
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+
+    grid = (512, 512, 512)
+    mesh = make_dpc_mesh((1,), devices=topo.devices[:1])
+    dec = _decomp_for(mesh, grid)
+    coords = jax.ShapeDtypeStruct(dec.boundary_coords.shape, jnp.int32,
+                                  sharding=NamedSharding(mesh, P(None, None)))
+    mask = jax.ShapeDtypeStruct(
+        grid, jnp.bool_, sharding=NamedSharding(mesh, P(*dec.names, None,
+                                                        None)))
+    compiled = _grid_program("cc", mesh, grid, False, 6, True, "auto",
+                             "replicated", 64).lower(mask, coords).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    ma = compiled.memory_analysis()
+    # with the binary search: 47848448 bytes of code, 7011358720 of
+    # temporaries; the cell's peak on the chip, 1805908992 bytes
+    assert ma.generated_code_size_in_bytes - 47848448 < 0.01 * 1805908992
+    # the join's program reads 290304 bytes more temporaries, the
+    # compiler's async-copy descriptors: within the same 1%
+    assert ma.temp_size_in_bytes <= 7011358720 * 1.01
+
+
+def test_value_search_holds_less_than_binary_search(one_chip):
+    """At the one-chip 512^3 shape (2^27 owned labels, 2 x 512^2 slots) the
+    join's temporaries stay under the binary search's: the chunks keep its
+    sorts a few tens of MB."""
+    from repro.core import _table
+    labels = jax.ShapeDtypeStruct((512 ** 3,), jnp.int32, sharding=one_chip)
+    table = jax.ShapeDtypeStruct((2 * 512 ** 2,), jnp.int32,
+                                 sharding=one_chip)
+    compiled = jax.jit(_table.value_substitute).lower(
+        labels, labels, table, table).compile()
+    # jnp.searchsorted's substitution read 2147613184 bytes (four labels
+    # arrays); one labels array is 536870912
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 536870912 + 64 * 2 ** 20
