@@ -59,7 +59,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
-from ._shardmap import shard_map_norep
+from ._shardmap import collective, shard_map_norep
 from ._table import (TableView, chase_view, check_converged, check_table_mode,
                      make_group_max, hook_propagate, materialize,
                      sharded_fixpoint, value_substitute)
@@ -304,10 +304,10 @@ def _halo_extend(ext, dim, name, n_blocks, fill):
         hi = jnp.full_like(hi_src, fill)
     else:
         p = lax.axis_index(name)
-        lo = lax.ppermute(lo_src, name,
-                          [(i, i + 1) for i in range(n_blocks - 1)])
-        hi = lax.ppermute(hi_src, name,
-                          [(i + 1, i) for i in range(n_blocks - 1)])
+        lo = collective(lax.ppermute, lo_src, name,
+                        [(i, i + 1) for i in range(n_blocks - 1)])
+        hi = collective(lax.ppermute, hi_src, name,
+                        [(i + 1, i) for i in range(n_blocks - 1)])
         lo = jnp.where(p == 0, fill, lo)
         hi = jnp.where(p == n_blocks - 1, fill, hi)
     return jnp.concatenate([lo, ext, hi], axis=dim)
@@ -342,7 +342,7 @@ def _gather_table(owned, dec: BlockDecomp):
             lo = lax.index_in_dim(owned, 0, a, keepdims=False)
             hi = lax.index_in_dim(owned, dec.local[a] - 1, a, keepdims=False)
             bt = jnp.stack([lo.reshape(-1), hi.reshape(-1)])   # (2, F_a)
-            g = lax.all_gather(bt, dec.names)              # (nblocks, 2, F_a)
+            g = collective(lax.all_gather, bt, dec.names)  # (nblocks, 2, F_a)
             parts.append(g.reshape(-1))
         return jnp.concatenate(parts)
 
@@ -427,9 +427,11 @@ class _ShardGeom:
         def axis_parts(src, a):
             L, name = dec.layout[a], dec.names[a]
             if L == 2:
-                return [lax.ppermute(src, name, [(0, 1), (1, 0)])]
-            lo = lax.ppermute(src, name, [(i, i + 1) for i in range(L - 1)])
-            hi = lax.ppermute(src, name, [(i + 1, i) for i in range(L - 1)])
+                return [collective(lax.ppermute, src, name, [(0, 1), (1, 0)])]
+            lo = collective(lax.ppermute, src, name,
+                            [(i, i + 1) for i in range(L - 1)])
+            hi = collective(lax.ppermute, src, name,
+                            [(i + 1, i) for i in range(L - 1)])
             p = lax.axis_index(name)
             return [jnp.where(p == 0, fill, lo),
                     jnp.where(p == L - 1, fill, hi)]
@@ -565,8 +567,8 @@ def _shard_geom_for(dec: BlockDecomp, connectivity: int) -> _ShardGeom:
 
 def _preduce_stats(dec: BlockDecomp, iters, rounds, ok):
     """Mesh-wide reductions of per-device sharded fixpoint stats."""
-    return (lax.pmax(iters, dec.names), rounds,
-            lax.pmin(ok.astype(jnp.int32), dec.names))
+    return (collective(lax.pmax, iters, dec.names), rounds,
+            collective(lax.pmin, ok.astype(jnp.int32), dec.names))
 
 
 # --- MS manifolds ------------------------------------------------------------
@@ -593,7 +595,7 @@ def _sharded_manifold_resolve(owned, dec: BlockDecomp, connectivity,
         return view.values, iters, ok
 
     def reduce_any(x):
-        return lax.pmax(x.astype(jnp.int32), dec.names) > 0
+        return collective(lax.pmax, x.astype(jnp.int32), dec.names) > 0
 
     stackT, _, rounds, iters, ok = sharded_fixpoint(
         T0, exchange, refine, reduce_any, max_rounds=max_iter)
@@ -672,7 +674,7 @@ def _manifold_block(order_blk, *, dec: BlockDecomp, connectivity,
         table_bytes = jnp.float32((geom.stack_size + geom.rows) * isz)
 
     stats = DPCStats(
-        local_iters=lax.pmax(local_iters, dec.names),
+        local_iters=collective(lax.pmax, local_iters, dec.names),
         table_iters=table_iters,
         stitch_rounds=jnp.int32(0),
         ghost_bytes=ghost_bytes,
@@ -846,7 +848,7 @@ def _sharded_cc_resolve(owned, mask_owned, coords, dec: BlockDecomp,
         return hook_propagate(stack, cut_max, group_max, max_iter)
 
     def reduce_any(x):
-        return lax.pmax(x.astype(jnp.int32), dec.names) > 0
+        return collective(lax.pmax, x.astype(jnp.int32), dec.names) > 0
 
     stackG, _, rounds, iters, ok = sharded_fixpoint(
         T0, exchange, refine, reduce_any, max_rounds=max_iter)
@@ -959,16 +961,16 @@ def _cc_block(mask_blk, coords=None, *, dec: BlockDecomp, connectivity,
                                   + geom.stack_size)
         # global fraction over in-domain slots (== the replicated number:
         # pad slots are mask-False on both paths, deviation (p))
-        masked_frac = (lax.psum(
-            jnp.sum(Ms[:geom.rows]).astype(jnp.float32), dec.names)
-            / jnp.float32(max(dec.n_valid_slots, 1)))
+        masked_frac = (collective(
+            lax.psum, jnp.sum(Ms[:geom.rows]).astype(jnp.float32),
+            dec.names) / jnp.float32(max(dec.n_valid_slots, 1)))
 
     # pad table slots are label -1 / mask False by construction (the input
     # mask is padded False, deviation (p)), so they are excluded here
     stats = DPCStats(
-        local_iters=lax.pmax(local_iters, dec.names),
+        local_iters=collective(lax.pmax, local_iters, dec.names),
         table_iters=table_iters,
-        stitch_rounds=lax.pmax(stitch_rounds, dec.names),
+        stitch_rounds=collective(lax.pmax, stitch_rounds, dec.names),
         ghost_bytes=ghost_bytes,
         masked_ghost_fraction=masked_frac,
         pad_fraction=jnp.float32(dec.pad_fraction),

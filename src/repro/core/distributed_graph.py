@@ -55,7 +55,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ._shardmap import shard_map_norep
+from ._shardmap import collective, shard_map_norep
 from ._table import (check_converged, check_table_mode, pointer_chase,
                      make_group_max, hook_propagate, sharded_fixpoint,
                      value_substitute)
@@ -378,7 +378,7 @@ def _cc_partition(local_mask, lgid, local_ghost, owned_lidx, es, er,
             payload = jnp.stack([cut_lab, cut_m.astype(dt)])
         else:
             payload = cut_lab[None]
-        g = lax.all_gather(payload, name)        # (nparts, rows, c_max)
+        g = collective(lax.all_gather, payload, name)  # (nparts, rows, c_max)
         T = g[:, 0, :].reshape(-1)
         M = (g[:, 1, :].reshape(-1) != 0) if gather_mask else (T >= 0)
 
@@ -444,7 +444,7 @@ def _cc_partition(local_mask, lgid, local_ghost, owned_lidx, es, er,
                                  own_row.dtype)
                 stack = stack.at[0].set(own_row)
                 for k, perm_k in enumerate(geom.round_perms):
-                    recv = lax.ppermute(own_row, name, perm_k)
+                    recv = collective(lax.ppermute, own_row, name, perm_k)
                     stack = stack.at[store[k]].set(recv, mode="drop")
                 return stack.reshape(-1)
             return exchange
@@ -470,7 +470,7 @@ def _cc_partition(local_mask, lgid, local_ghost, owned_lidx, es, er,
             return hook_propagate(stack, cut_max, group_max, table_max_iter)
 
         def reduce_any(x):
-            return lax.pmax(x.astype(jnp.int32), name) > 0
+            return collective(lax.pmax, x.astype(jnp.int32), name) > 0
 
         stackG, _, rounds, iters, ok = sharded_fixpoint(
             cut_lab, exchange, refine, reduce_any,
@@ -488,7 +488,7 @@ def _cc_partition(local_mask, lgid, local_ghost, owned_lidx, es, er,
                            stackG[jnp.clip(sidx, 0, size - 1)], owned)
         final = value_substitute(owned, chased, sorted_vals, stackG[perm])
 
-        table_iters = lax.pmax(iters, name)
+        table_iters = collective(lax.pmax, iters, name)
         exch_rounds = rounds
         comm = rounds + jnp.int32(1)     # +1: the static label/mask stacks
         halo = size - dec.c_max
@@ -499,15 +499,15 @@ def _cc_partition(local_mask, lgid, local_ghost, owned_lidx, es, er,
         table_bytes = jnp.float32((2 * size + dec.c_max) * isz + size)
         # global fraction over real slots (== the replicated number: pad
         # slots are mask-False on both paths, deviation (p))
-        masked_frac = (lax.psum(
-            jnp.sum(Ms[:dec.c_max]).astype(jnp.float32), name)
+        masked_frac = (collective(
+            lax.psum, jnp.sum(Ms[:dec.c_max]).astype(jnp.float32), name)
             / jnp.float32(max(dec.n_cut, 1)))
-        converged = lax.pmin(ok.astype(jnp.int32), name)
+        converged = collective(lax.pmin, ok.astype(jnp.int32), name)
 
     stats = GraphDPCStats(
-        local_iters=lax.pmax(res.n_compress_iter, name),
+        local_iters=collective(lax.pmax, res.n_compress_iter, name),
         table_iters=table_iters,
-        stitch_rounds=lax.pmax(res.n_rounds, name),
+        stitch_rounds=collective(lax.pmax, res.n_rounds, name),
         ghost_bytes=ghost_bytes,
         masked_ghost_fraction=masked_frac,
         comm_phases=comm,

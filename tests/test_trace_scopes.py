@@ -6,9 +6,16 @@ reaches the compiled program's op metadata (`op_name`), and from there the
 `jax.profiler.TraceAnnotation` spans (`topology.submit`, `dpc.dispatch`,
 `dpc.host_read`).  The benchmark's per-layer readers (`bench/layers.py`)
 count on both, so a refactor that drops a scope or a span fails here.
-One CPU device, a (1,) mesh, tiny grids.
+One CPU device, a (1,) mesh, tiny grids; the (2, 2) mesh, where every
+collective has to lie under `dpc.exchange`, in a subprocess with 4 virtual
+CPU devices (the device-count flag is never set in the test process).
 """
+import json
+import os
 import re
+import subprocess
+import sys
+import textwrap
 from pathlib import Path
 
 import numpy as np
@@ -23,9 +30,12 @@ from repro.core.distributed import _decomp_for, _flip_order, _grid_program
 from repro.core.ms_segmentation import _pair_hash
 from repro.topology import TopologyRequest, submit
 
+_SRC = os.path.join(os.path.dirname(__file__), "..", "src")
+
 GRID = (8, 6, 4)
 N = int(np.prod(GRID))
 LOCAL = {"dpc.halo", "dpc.init", "dpc.doubling", "dpc.ids"}
+COLLECTIVE = re.compile(r"^(all-gather|collective-permute|all-reduce)")
 TABLE = {"dpc.table.gather", "dpc.table.substitute"}
 SCOPES = {
     "manifold": LOCAL | TABLE | {"dpc.table.chase"},
@@ -67,6 +77,83 @@ def test_grid_program_names_each_layer(kind, table_mode):
     if kind == "cc":
         assert any(re.search(r"/dpc\.cc_stitch/(.*/)?dpc\.doubling/", n)
                    for n in names)
+
+
+_MESH_2X2 = textwrap.dedent(r"""
+    import json, os, re
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import jax
+    import jax.numpy as jnp
+    from repro.core import make_dpc_mesh
+    from repro.core.distributed import _decomp_for, _grid_program
+
+    grid = (8, 6, 4)
+    mesh = make_dpc_mesh((2, 2))
+    out = {}
+    for kind in ("manifold", "cc"):
+        for mode in ("replicated", "sharded"):
+            if kind == "manifold":
+                args = (jnp.arange(192, dtype=jnp.int32).reshape(grid),)
+            else:
+                args = (jnp.ones(grid, bool),
+                        _decomp_for(mesh, grid).boundary_coords_dev)
+            prog = _grid_program(kind, mesh, grid, False, 6, True, "auto",
+                                 mode, 64)
+            text = prog.lower(*args).compile().as_text()
+            # (opcode, op_name or "") of every instruction
+            ops = []
+            for line in text.splitlines():
+                op = re.search(r"= [^=]*? ([a-z][\w-]*)\(", line)
+                name = re.search(r'op_name="([^"]*)"', line)
+                if op:
+                    ops.append((op.group(1), name.group(1) if name else ""))
+            out[f"{kind}.{mode}"] = ops
+    print("RESULT " + json.dumps(out))
+""")
+
+
+def innermost_dpc(op_name: str):
+    """The innermost `dpc.` component of an op name, whole (the layer
+    rule of `bench/layers.py` takes it up to its first dot)."""
+    found = [p for p in op_name.split("/") if p.startswith("dpc.")]
+    return found[-1] if found else None
+
+
+@pytest.fixture(scope="module")
+def ops_2x2():
+    env = dict(os.environ, PYTHONPATH=_SRC, JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)
+    proc = subprocess.run([sys.executable, "-c", _MESH_2X2], env=env,
+                          capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    line = next(x for x in proc.stdout.splitlines()
+                if x.startswith("RESULT "))
+    return json.loads(line[len("RESULT "):])
+
+
+@pytest.mark.parametrize("table_mode", ["replicated", "sharded"])
+@pytest.mark.parametrize("kind", ["manifold", "cc"])
+def test_2x2_collectives_are_the_exchange(ops_2x2, kind, table_mode):
+    """On a (2, 2) mesh every collective (the halo and table `ppermute`s,
+    the table's `all_gather`, the `pmax`/`pmin`/`psum` of the convergence
+    checks and of `DPCStats`) has `dpc.exchange` as its innermost `dpc.`
+    scope, and every layer scope of the one-chip program is still there."""
+    ops = ops_2x2[f"{kind}.{table_mode}"]
+    coll = [(op, name) for op, name in ops if COLLECTIVE.match(op)]
+    kinds = {COLLECTIVE.match(op).group(1) for op, _ in coll}
+    want_kinds = {"collective-permute", "all-reduce"}
+    if table_mode == "replicated":
+        want_kinds.add("all-gather")
+    assert want_kinds <= kinds, f"collectives missing: {want_kinds - kinds}"
+    stray = [(op, name) for op, name in coll
+             if innermost_dpc(name) != "dpc.exchange"]
+    assert not stray, f"collectives outside dpc.exchange: {stray}"
+    found = scopes_in(name for _, name in ops)
+    want = (SCOPES[kind] - ABSENT.get((kind, table_mode), set())
+            | {"dpc.exchange"})
+    assert want <= found, f"scopes missing: {sorted(want - found)}"
+    if table_mode == "sharded":
+        assert "dpc.table" in found
 
 
 @pytest.mark.parametrize("fn, args, scope", [
