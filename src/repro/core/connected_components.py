@@ -32,22 +32,26 @@ class CCResult(NamedTuple):
     n_compress_iter: jax.Array
 
 
-def _grid_stitch(d: jax.Array, mask_flat: jax.Array, shape, connectivity: int,
-                 sentinel: int) -> jax.Array:
-    """One stitch pass (Alg. 3 lines 25-29) on a structured grid, as a
-    scatter-max: for each directed neighbor pair (v, u) with both masked,
-    d[d[v]] <- max(d[d[v]], d[u])."""
-    d_grid = d.reshape(shape)
-    m_grid = mask_flat.reshape(shape)
-    out = d
-    for off in neighbor_offsets(len(shape), connectivity):
-        u_label = shift_fill(d_grid, off, -1).ravel()          # d[u]
-        valid = mask_flat & (shift_fill(m_grid, off, False).ravel())
-        tgt = jnp.where(valid, d, sentinel)                    # index d[v]
-        val = jnp.where(valid, u_label, -1)
-        tgt, val = materialize((tgt, val))
-        out = out.at[tgt].max(val, mode="drop")
-    return out
+def grid_stitch(d: jax.Array, mask: jax.Array, connectivity: int,
+                sentinel: int) -> jax.Array:
+    """One stitch pass (Alg. 3 lines 25-29) on a structured grid:
+    d[d[v]] <- max(d[d[v]], max over masked neighbors u of d[u]), for every
+    masked v.  `d` is flat, `mask` the grid; both grid backends run it.
+
+    Each vertex's maximum over the stencil is taken first, in one dense pass
+    over shifted views, and scattered once: between rounds `d` is fully
+    compressed, so every target d[v] is a root, and a scatter-max per
+    neighbor offset would only split the same max over `connectivity`
+    scatters (each a block-sized sort plus scatter on a TPU)."""
+    d_grid = d.reshape(mask.shape)
+    best = jnp.full(mask.shape, -1, d.dtype)
+    for off in neighbor_offsets(mask.ndim, connectivity):
+        u_masked = shift_fill(mask, off, False)
+        best = jnp.maximum(best, jnp.where(u_masked,
+                                           shift_fill(d_grid, off, -1), -1))
+    best = jnp.where(mask, best, -1).ravel()
+    tgt, val = materialize((jnp.where(best >= 0, d, sentinel), best))
+    return d.at[tgt].max(val, mode="drop")
 
 
 def _graph_stitch(d: jax.Array, mask: jax.Array, senders: jax.Array,
@@ -92,9 +96,8 @@ def connected_components_grid(mask: jax.Array, connectivity: int = 6,
     # lazy: repro.kernels imports repro.core.steepest at module load
     from repro.kernels.ops import fused_local_phase
     n = mask.size
-    mask_flat = mask.ravel().astype(bool)
     d0 = fused_local_phase(mask, connectivity, mode="cc", impl=fused_impl)
-    stitch = lambda d: _grid_stitch(d, mask_flat, mask.shape, connectivity, n)
+    stitch = lambda d: grid_stitch(d, mask.astype(bool), connectivity, n)
     res = _cc_fixpoint(d0.ravel(), stitch)
     return CCResult(res.labels.reshape(mask.shape), res.n_rounds,
                     res.n_compress_iter)

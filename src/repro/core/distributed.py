@@ -63,8 +63,9 @@ from ._shardmap import collective, shard_map_norep
 from ._table import (TableView, chase_view, check_converged, check_table_mode,
                      make_group_max, hook_propagate, materialize,
                      sharded_fixpoint, value_substitute)
+from .connected_components import grid_stitch
 from .stats import DPCStats
-from .steepest import neighbor_offsets, shift_fill
+from .steepest import neighbor_offsets
 from .pathcompress import path_compress
 
 AXIS = "shards"                 # legacy 1-D axis name (make_flat_mesh interop)
@@ -727,21 +728,6 @@ def distributed_manifold(order, mesh: Mesh, connectivity: int = 6,
 # --- connected components ----------------------------------------------------
 
 
-def _ext_stitch(d, mask_ext, connectivity, sentinel):
-    """Stitch on the extended block in local ids (Alg. 3 ll. 25-29):
-    scatter-max at position d[v]."""
-    out = d
-    dg = d.reshape(mask_ext.shape)
-    m = mask_ext.ravel()
-    for off in neighbor_offsets(mask_ext.ndim, connectivity):
-        u_label = shift_fill(dg, off, -1).ravel()
-        valid = m & shift_fill(mask_ext, off, False).ravel() & (u_label >= 0)
-        tgt, val = materialize((jnp.where(valid, out, sentinel),
-                                jnp.where(valid, u_label, -1)))
-        out = out.at[tgt].max(val, mode="drop")
-    return out
-
-
 def _cc_local_fixpoint(d, mask_ext, connectivity, max_rounds=64):
     d, its0 = path_compress(d)
     sentinel = d.size
@@ -752,7 +738,7 @@ def _cc_local_fixpoint(d, mask_ext, connectivity, max_rounds=64):
 
     def body(s):
         cur, _, r, its = s
-        st = _ext_stitch(cur, mask_ext, connectivity, sentinel)
+        st = grid_stitch(cur, mask_ext, connectivity, sentinel)
         nxt, it = path_compress(st)
         return nxt, jnp.any(nxt != cur), r + jnp.int32(1), its + it
 

@@ -138,6 +138,12 @@ def test_one_chip_cc_program_compiles_at_512(topo, monkeypatch):
     # the join's program reads 290304 bytes more temporaries, the
     # compiler's async-copy descriptors: within the same 1%
     assert ma.temp_size_in_bytes <= 7011358720 * 1.01
+    # with one scatter per stitch round, not one per neighbor offset:
+    # 34636288 bytes of code and 2699485184 of temporaries, against
+    # 61694976 and 7011649024 with the per-offset loop; a stitch split
+    # again would climb back past those
+    assert ma.generated_code_size_in_bytes <= 61694976
+    assert ma.temp_size_in_bytes <= 7011649024
 
 
 def test_value_search_holds_less_than_binary_search(one_chip):
